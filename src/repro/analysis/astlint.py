@@ -23,7 +23,7 @@ Rules (catalog + rationale in docs/static-analysis.md):
 The call graph is intentionally lightweight: same-module calls by name,
 cross-module calls through ``import``/``from`` aliases, plus any known
 function *referenced* as a call argument (covers ``lax.scan(body, ...)``,
-``pallas_call(kernel, ...)``, ``shard_map_compat(body, ...)``).
+``pallas_call(kernel, ...)``, ``jax.shard_map(body, ...)``).
 """
 from __future__ import annotations
 
@@ -172,8 +172,7 @@ class _Repo:
             chain = _attr_chain(node.func)
             if chain is None or not node.args:
                 continue
-            if chain[-1] in ("pallas_call", "shard_map",
-                            "shard_map_compat"):
+            if chain[-1] in ("pallas_call", "shard_map"):
                 target = node.args[0]
                 if isinstance(target, ast.Call):   # partial(kernel, ...)
                     target = target.args[0] if target.args else target

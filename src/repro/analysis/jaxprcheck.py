@@ -23,7 +23,7 @@ pallas_call) and asserts the representation contracts:
 * **JXP005** — no ``pure_callback`` / ``io_callback`` /
   ``debug_callback`` (jax.debug.print) inside any serving step.
 
-Empirical anchors (jax 0.4.37, tiny 2-layer configs): the full decode
+Empirical anchors (tiny 2-layer configs): the full decode
 carries 16 int8-plane dots (8 of them shift-fed MSB dots), the draft 8
 (0 shift-fed); a 2x2 mesh decode carries exactly 2 int32 ``psum`` and
 2 f32 ``pmax`` eqns over the model axis.
@@ -35,7 +35,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal
 
 from .findings import Finding
 

@@ -53,14 +53,36 @@ class HardwareConfig:
     sparqle_power_ovh: float = 1.07
     sparqle_area_ovh: float = 1.055
     pipeline_fill_cycles: int = 64
-    # system-level roofline peaks (per chip; TPU-v5e-class reference):
-    # live attribution (obs/attribution.py) and benchmarks/roofline.py
-    # normalize achieved FLOP/s, HBM bytes/s and interconnect bytes/s
-    # against these — they describe the serving substrate, not the §4
-    # SRAM-level accelerator modeled by the knobs above
-    peak_flops: float = 197e12           # FLOP/s
+    # system-level roofline peaks (per chip; TPU v5e reference, see
+    # DEVICE_PEAKS): live attribution (obs/attribution.py) and
+    # benchmarks/roofline.py normalize achieved FLOP/s, HBM bytes/s and
+    # interconnect bytes/s against these — they describe the serving
+    # substrate, not the §4 SRAM-level accelerator modeled by the knobs
+    # above
+    peak_flops: float = 197e12           # FLOP/s, bf16
+    peak_int8_ops: float = 393e12        # OP/s, int8
     hbm_bw: float = 819e9                # B/s
     link_bw: float = 50e9                # B/s per ICI link
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s
+# int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect (4 links of 50 GB/s).
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": dict(peak_flops=197e12, peak_int8_ops=393e12,
+                        hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def hardware_for(device_kind: str) -> HardwareConfig:
+    """The roofline peaks of ``device_kind``; raises for a device whose
+    peaks are not in :data:`DEVICE_PEAKS` (no borrowed defaults)."""
+    if device_kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); pass hw= explicitly")
+    return HardwareConfig(**DEVICE_PEAKS[device_kind])
 
 
 @dataclasses.dataclass

@@ -330,8 +330,9 @@ def quantize_leaf(
     )
 
 
-def quantize_model_params(
-    params: Dict[str, Any],
+def leaf_plan(
+    path: str,
+    ndim: int,
     *,
     w_bits: int = 4,
     k_percent: float = 50.0,
@@ -342,13 +343,53 @@ def quantize_model_params(
     per_layer_lh: Optional[Dict[str, tuple]] = None,
     tile_k: int = 128,
     wire_format: str = "unpacked",
+):
+    """How :func:`quantize_model_params` serves the projection at ``path``.
+
+    Returns ``(quantize, stacked)``: ``quantize`` maps one (K, N) or
+    (E, K, N) float slice to its :class:`SparqleLinear`, and ``stacked``
+    says the leaf carries a leading layer dim that is quantized one slice
+    at a time and re-stacked. ``per_layer_lh`` optionally maps path
+    prefixes to (l, h) pairs (the Algorithm-1 layerwise constants);
+    unmatched paths use the global pair.
+    """
+    l, h = clip_l, clip_h
+    if per_layer_lh:
+        for pref, (pl_, ph_) in per_layer_lh.items():
+            if path.startswith(pref):
+                l, h = pl_, ph_
+                break
+
+    def quantize(w):
+        return quantize_leaf(
+            w, w_bits=w_bits, k_percent=k_percent, clip_l=l, clip_h=h,
+            mode=mode, enable_clipping=enable_clipping, tile_k=tile_k,
+            wire_format=wire_format)
+
+    # routed-expert weights are (E,K,N)-batched; shared-expert weights
+    # (w_shared_*) are plain 2D despite living in moe/
+    name = path.rsplit("/", 1)[-1]
+    is_expert = (("/moe/" in path or path.startswith("moe/"))
+                 and "shared" not in name)
+    # leaf ranks: 2 = plain (K,N); 3 = experts (E,K,N) when under a moe/
+    # subtree else layer-stacked (L,K,N); 4 = layer-stacked experts
+    # (L,E,K,N).
+    if ndim == 2 or (ndim == 3 and is_expert):
+        return quantize, False
+    if ndim in (3, 4):
+        return quantize, True
+    raise ValueError(f"{path}: rank {ndim}")
+
+
+def quantize_model_params(
+    params: Dict[str, Any],
+    **quant_kw,
 ) -> Dict[str, Any]:
     """Rewrite every projection leaf of a param tree into SPARQLe form.
 
-    ``per_layer_lh`` optionally maps path prefixes to (l, h) pairs (the
-    Algorithm-1 layerwise constants); unmatched paths use the global pair.
-    ``wire_format='packed'`` serves every projection's activations through
-    the packed sub-precision wire format.
+    Keyword arguments are :func:`leaf_plan`'s (bits, clipping, tile and
+    wire-format choices). ``wire_format='packed'`` serves every
+    projection's activations through the packed sub-precision wire format.
     """
 
     def walk(tree, prefix=""):
@@ -358,31 +399,13 @@ def quantize_model_params(
             if isinstance(v, dict):
                 out[k] = walk(v, path)
             elif is_quantizable(path, v):
-                l, h = clip_l, clip_h
-                if per_layer_lh:
-                    for pref, (pl_, ph_) in per_layer_lh.items():
-                        if path.startswith(pref):
-                            l, h = pl_, ph_
-                            break
-                q1 = lambda w: quantize_leaf(  # noqa: E731
-                    w, w_bits=w_bits, k_percent=k_percent, clip_l=l,
-                    clip_h=h, mode=mode, enable_clipping=enable_clipping,
-                    tile_k=tile_k, wire_format=wire_format)
-                # routed-expert weights are (E,K,N)-batched; shared-expert
-                # weights (w_shared_*) are plain 2D despite living in moe/
-                is_expert = (("/moe/" in path or path.startswith("moe/"))
-                             and "shared" not in k)
-                # leaf ranks: 2 = plain (K,N); 3 = experts (E,K,N) when under
-                # a moe/ subtree else layer-stacked (L,K,N); 4 = layer-stacked
-                # experts (L,E,K,N).
-                if v.ndim == 2 or (v.ndim == 3 and is_expert):
-                    out[k] = q1(v)
-                elif v.ndim in (3, 4):
+                q1, stacked = leaf_plan(path, v.ndim, **quant_kw)
+                if stacked:
                     sls = [q1(v[i]) for i in range(v.shape[0])]
                     out[k] = jax.tree_util.tree_map(
                         lambda *xs: jnp.stack(xs), *sls)
                 else:
-                    raise ValueError(f"{path}: rank {v.ndim}")
+                    out[k] = q1(v)
             else:
                 out[k] = v
         return out

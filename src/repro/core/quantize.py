@@ -58,7 +58,10 @@ def quantize_weights(w: jax.Array, bits: int = 4, axis: int = -1) -> QuantizedTe
     """
     lo, hi = _qrange(bits)
     amax = jnp.max(jnp.abs(w), axis=axis, keepdims=True)
-    scale = jnp.maximum(amax / hi, 1e-8)
+    # multiply by the reciprocal: inside jit XLA rewrites a division by a
+    # constant this way, so writing it out makes eager and jitted
+    # quantization agree bit for bit
+    scale = jnp.maximum(amax * (1.0 / hi), 1e-8)
     q = jnp.clip(jnp.round(w / scale), lo, hi).astype(jnp.int8)
     return QuantizedTensor(q=q, scale=scale.astype(jnp.float32),
                            zero=jnp.zeros_like(scale, jnp.float32), bits=bits)

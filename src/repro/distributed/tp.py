@@ -84,19 +84,6 @@ def tp_ctx() -> Optional[TPContext]:
     return _TP.ctx
 
 
-# ---------------------------------------------------------------------------
-# shard_map across jax versions (mirrors models/moe.py)
-# ---------------------------------------------------------------------------
-
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def mesh_axis_size(mesh: Optional[Mesh], axis: str) -> int:
     if mesh is None:
         return 1

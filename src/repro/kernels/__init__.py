@@ -11,10 +11,28 @@
 
 Each kernel ships with a pure-jnp oracle in ref.py and interpret-mode
 allclose sweeps in tests/test_kernels.py; ops.py holds the jit'd public
-wrappers (padding, backend dispatch).
+wrappers (padding). Every kernel is launched through :func:`pallas_call`,
+which picks compiled or interpreted execution from the platform the
+program is lowered for — callers never choose.
 """
-from jax.experimental.pallas import tpu as _pltpu
+import jax
+from jax.experimental import pallas as pl
 
-# jax < 0.6 names this TPUCompilerParams; one shim shared by all kernels
-CompilerParams = (getattr(_pltpu, "CompilerParams", None)
-                  or _pltpu.TPUCompilerParams)
+
+def pallas_call(kernel, **kwargs):
+    """``pl.pallas_call`` whose execution mode follows the lowering platform.
+
+    On TPU the kernel is compiled by Mosaic; on CPU (the test platform) it
+    runs in the Pallas interpreter. Lowering for any other platform raises
+    — there is no silent interpreter fallback on an accelerator. The choice
+    is made per lowering (``jax.lax.platform_dependent``), so a program
+    compiled for a described TPU from a CPU host gets the real kernel.
+    """
+    compiled = pl.pallas_call(kernel, **kwargs)
+    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+
+    def call(*args):
+        return jax.lax.platform_dependent(*args, cpu=interpreted,
+                                          tpu=compiled)
+
+    return call

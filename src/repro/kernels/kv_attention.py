@@ -10,12 +10,18 @@ halves the dominant decode stream (the XLA path materializes the
 dequantized cache between ops unless fusion cooperates; the kernel makes
 the fusion structural).
 
-Layout: one grid step handles one (batch, kv-head) pair and one cache
-block of ``bs`` tokens (innermost, 'arbitrary'): running max/denominator
-and the (G, hd) output accumulator live in VMEM scratch across the cache
-scan — the standard flash-decoding structure re-tiled for VMEM.
+Layout: one grid step handles one sequence and one cache block of ``bs``
+tokens (innermost grid axis, 'arbitrary'). The block spans every KV head
+— (1, bs, KVH, hd//2) — so its last two dims equal the array's, which is
+what the TPU's (8, 128) tiling admits; the body walks the heads in a
+static loop, each head running the same (G, hd) x (bs, hd) online-softmax
+update on its own running max/denominator/accumulator in VMEM scratch
+(the flash-decoding structure re-tiled for VMEM). A head's arithmetic
+never touches another head's values, so its bits do not depend on how
+many heads share the block (KV-head sharding is exact). Positions and
+block tables are scalar-prefetched into SMEM.
 
-Three entry points share the same kernel body (``_flash_step``):
+Three entry points share the same kernel body (``_scan_step``):
 
   * :func:`kv4_decode_attention`        — contiguous (B, S, KVH, …) cache;
   * :func:`kv4_paged_decode_attention`  — a paged pool (P, page, KVH, …)
@@ -26,7 +32,7 @@ Three entry points share the same kernel body (``_flash_step``):
   * :func:`kv4_paged_verify_attention`  — the multi-token (q > 1) variant
     for self-speculative verification: T window tokens per sequence, each
     causally masked to its own absolute position ``pos + t``. The window
-    axis is a *grid* dimension, so every (b, h, t) cell runs the exact
+    axis is a *grid* dimension, so every (b, t) cell runs the exact
     single-token computation (same block shapes, same dot shapes, same
     accumulation order) — bit-exact against a loop of T single-token
     paged decode calls by construction.
@@ -47,72 +53,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
+from repro.kernels import pallas_call
 
 NEG_INF = -2.0e38
 
 
-def _unpack4(q):  # int8 packed nibbles -> two sign-extended int8 planes
-    lo = jnp.right_shift(jnp.left_shift(q, 4), 4)
-    hi = jnp.right_shift(q, 4)
-    return lo, hi
+def _dequant(q_ref, s_ref, h: int, width: int) -> jax.Array:
+    """KV head ``h`` of a packed cache block -> (bs, hd) f32.
+
+    ``q_ref`` is a (1, bs, KVH, hd*width//8) int8 block whose bytes hold
+    ``8 // width`` two's-complement fields, lowest field first; ``s_ref``
+    the (1, bs, KVH) per-token-head scales. Fields are widened to int32
+    before the shifts and the interleave (Mosaic lays int32 out natively).
+    """
+    x = q_ref[0, :, h, :].astype(jnp.int32)
+    fields = [jnp.right_shift(jnp.left_shift(x, 32 - width * (f + 1)),
+                              32 - width)
+              for f in range(8 // width)]
+    x_int = jnp.stack(fields, axis=-1).reshape(x.shape[0], -1)
+    return x_int.astype(jnp.float32) * s_ref[0, :, pl.ds(h, 1)]
 
 
-def _unpack2(q):  # int8 packed 2-bit fields -> four sign-extended planes
-    f0 = jnp.right_shift(jnp.left_shift(q, 6), 6)
-    f1 = jnp.right_shift(jnp.left_shift(q, 4), 6)
-    f2 = jnp.right_shift(jnp.left_shift(q, 2), 6)
-    f3 = jnp.right_shift(q, 6)
-    return f0, f1, f2, f3
+def _scan_step(pos, s_idx, q_of, kv_of, out_ref, m_ref, l_ref, acc_ref,
+               *, n_s: int, bs: int, scale: float):
+    """One cache-block step of the online-softmax scan, for every KV head.
 
-
-def _dequant4_block(q_ref, s_ref, bs):
-    """Unpack + dequantize one packed-int4 cache block in VMEM -> (bs, hd)."""
-    qq = q_ref[...].reshape(bs, -1)                       # (bs, hd//2) int8
-    ss = s_ref[...].reshape(bs)
-    lo, hi = _unpack4(qq)
-    x_int = jnp.stack([lo, hi], axis=-1).reshape(bs, -1)  # (bs, hd)
-    return x_int.astype(jnp.float32) * ss[:, None]
-
-
-def _dequant2_block(q_ref, s_ref, bs):
-    """Unpack + dequantize one packed-int2 (KV2 tier) block -> (bs, hd)."""
-    qq = q_ref[...].reshape(bs, -1)                       # (bs, hd//4) int8
-    ss = s_ref[...].reshape(bs)
-    f0, f1, f2, f3 = _unpack2(qq)
-    x_int = jnp.stack([f0, f1, f2, f3], axis=-1).reshape(bs, -1)
-    return x_int.astype(jnp.float32) * ss[:, None]
-
-
-def _flash_core(pos, s_idx, q_ref, k, v, out_ref, m_ref, l_ref, acc_ref,
-                *, n_s: int, bs: int, scale: float):
-    """The online-softmax scan step on dequantized (bs, hd) k/v blocks.
-    Every entry point feeds this same f32 computation, so two call paths
-    that hand it elementwise-identical k/v produce identical bits."""
-    hd = out_ref.shape[-1]
-
+    ``pos`` is the query's absolute position (a scalar) and ``s_idx`` the
+    block's place along the cache axis; ``q_of(h)`` gives head ``h``'s
+    (G, hd) query rows and ``kv_of(h)`` its dequantized (bs, hd) k and v.
+    Every entry point maps its own grid and layout onto these, so the f32
+    computation (and therefore the bits) is identical across them."""
     @pl.when(s_idx == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].reshape(-1, hd).astype(jnp.float32)   # (G, hd)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
     # causal validity: absolute cache position <= pos
     j = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    s = jnp.where(j <= pos, s, NEG_INF)                  # (G, bs)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    for h in range(acc_ref.shape[0]):
+        k, v = kv_of(h)
+        q = q_of(h).astype(jnp.float32)                   # (G, hd)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(j <= pos, s, NEG_INF)               # (G, bs)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
     @pl.when(s_idx == n_s - 1)
     def _drain():
@@ -121,30 +114,27 @@ def _flash_core(pos, s_idx, q_ref, k, v, out_ref, m_ref, l_ref, acc_ref,
                             out_ref.dtype).reshape(out_ref.shape)
 
 
-def _flash_step(pos, s_idx, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, out_ref,
-                m_ref, l_ref, acc_ref, *, n_s: int, bs: int, scale: float):
-    """One cache-block step of the online-softmax scan for ONE query row
-    group. ``pos`` is the query's absolute position (a scalar), ``s_idx``
-    its place along the cache-block grid axis — every entry point maps its
-    own grid onto these two values, so the f32 computation (and therefore
-    the bits) is identical across layouts.
-    """
-    # unpack + dequantize this cache block in VMEM
-    k = _dequant4_block(kq_ref, ks_ref, bs)
-    v = _dequant4_block(vq_ref, vs_ref, bs)
-    _flash_core(pos, s_idx, q_ref, k, v, out_ref, m_ref, l_ref, acc_ref,
-                n_s=n_s, bs=bs, scale=scale)
+def _kv4(kq_ref, ks_ref, vq_ref, vs_ref):
+    return lambda h: (_dequant(kq_ref, ks_ref, h, 4),
+                      _dequant(vq_ref, vs_ref, h, 4))
+
+
+def _scratch(kvh: int, g: int, hd: int):
+    return [
+        pltpu.VMEM((kvh, g, 1), jnp.float32),    # running max
+        pltpu.VMEM((kvh, g, 1), jnp.float32),    # running denominator
+        pltpu.VMEM((kvh, g, hd), jnp.float32),   # output accumulator
+    ]
 
 
 def _kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, out_ref,
             m_ref, l_ref, acc_ref, *, n_s: int, bs: int, scale: float):
-    _flash_step(pos_ref[0], pl.program_id(2), q_ref, kq_ref, ks_ref,
-                vq_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref,
-                n_s=n_s, bs=bs, scale=scale)
+    _scan_step(pos_ref[pl.program_id(0)], pl.program_id(1),
+               lambda h: q_ref[0, h], _kv4(kq_ref, ks_ref, vq_ref, vs_ref),
+               out_ref, m_ref, l_ref, acc_ref, n_s=n_s, bs=bs, scale=scale)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bs", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bs",))
 def kv4_decode_attention(
     q: jax.Array,       # (B, KVH, G, hd) — grouped query heads
     k_q: jax.Array,     # (B, S, KVH, hd//2) int8, packed nibbles
@@ -154,7 +144,6 @@ def kv4_decode_attention(
     pos: jax.Array,     # (B,) int32 — current position (inclusive)
     *,
     bs: int = 512,
-    interpret: bool = True,
 ) -> jax.Array:
     """Returns (B, KVH, G, hd) attention output. Cache stays packed-int4
     in HBM; unpack+dequant are fused into the attention block scan."""
@@ -163,34 +152,36 @@ def kv4_decode_attention(
     assert hdp * 2 == hd, (hd, hdp)
     assert s % bs == 0, (s, bs)
     n_s = s // bs
-    scale = hd ** -0.5
 
-    grid = (b, kvh, n_s)
-    return pl.pallas_call(
-        functools.partial(_kernel, n_s=n_s, bs=bs, scale=scale),
-        grid=grid,
+    kv = pl.BlockSpec((1, bs, kvh, hdp), lambda ib, isb, p: (ib, isb, 0, 0))
+    sc = pl.BlockSpec((1, bs, kvh), lambda ib, isb, p: (ib, isb, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, n_s),
         in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, isb: (ib,)),           # pos
-            pl.BlockSpec((1, 1, g, hd), lambda ib, ih, isb: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hdp),
-                         lambda ib, ih, isb: (ib, isb, ih, 0)),      # k_q
-            pl.BlockSpec((1, bs, 1), lambda ib, ih, isb: (ib, isb, ih)),
-            pl.BlockSpec((1, bs, 1, hdp),
-                         lambda ib, ih, isb: (ib, isb, ih, 0)),      # v_q
-            pl.BlockSpec((1, bs, 1), lambda ib, ih, isb: (ib, isb, ih)),
+            pl.BlockSpec((1, kvh, g, hd), lambda ib, isb, p: (ib, 0, 0, 0)),
+            kv, sc, kv, sc,
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda ib, ih, isb: (ib, ih, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, g, hd),
+                               lambda ib, isb, p: (ib, 0, 0, 0)),
+        scratch_shapes=_scratch(kvh, g, hd),
+    )
+    return pallas_call(
+        functools.partial(_kernel, n_s=n_s, bs=bs, scale=hd ** -0.5),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),     # running max
-            pltpu.VMEM((g, 1), jnp.float32),     # running denominator
-            pltpu.VMEM((g, hd), jnp.float32),    # output accumulator
-        ],
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(pos, q, k_q, k_s, v_q, v_s)
+
+
+def _page_specs(ps: int, kvh: int, width: int, page_of):
+    """Block specs of one page's packed values and scales, all KV heads;
+    ``page_of(*grid_idx, *prefetch_refs)`` names the physical page."""
+    kv = pl.BlockSpec((1, ps, kvh, width),
+                      lambda *a: (page_of(*a), 0, 0, 0))
+    sc = pl.BlockSpec((1, ps, kvh), lambda *a: (page_of(*a), 0, 0))
+    return [kv, sc, kv, sc]
 
 
 def _paged_kernel(bt_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
@@ -202,7 +193,7 @@ def _paged_kernel(bt_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
             m_ref, l_ref, acc_ref, n_s=n_s, bs=bs, scale=scale)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def kv4_paged_decode_attention(
     q: jax.Array,             # (B, KVH, G, hd) — grouped query heads
     k_pages: jax.Array,       # (P, ps, KVH, hd//2) int8, packed nibbles
@@ -211,8 +202,6 @@ def kv4_paged_decode_attention(
     v_scale_pages: jax.Array, # (P, ps, KVH) f32
     block_tables: jax.Array,  # (B, Pmax) int32 — seq-order page ids
     pos: jax.Array,           # (B,) int32 — current position (inclusive)
-    *,
-    interpret: bool = True,
 ) -> jax.Array:
     """Decode attention over a *paged* packed-KV4 pool.
 
@@ -220,63 +209,53 @@ def kv4_paged_decode_attention(
     tokens ``[i*ps, (i+1)*ps)``. Entries past the sequence's last page may
     point anywhere (conventionally the null page 0): the absolute-position
     causal mask ``i*ps + offset <= pos[b]`` discards them. The table is
-    scalar-prefetched so page ids are available to the DMA index maps —
-    the pool is only ever touched one page per grid step, in wire format.
+    scalar-prefetched (flattened, so SMEM holds no padded 2-D array) and
+    page ids feed the DMA index maps — the pool is only ever touched one
+    page per grid step, in wire format.
     """
     b, kvh, g, hd = q.shape
-    n_pages, ps, _, hdp = k_pages.shape
+    _, ps, _, hdp = k_pages.shape
     _, n_s = block_tables.shape
     assert hdp * 2 == hd, (hd, hdp)
-    scale = hd ** -0.5
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kvh, n_s),
+        num_scalar_prefetch=2,
+        grid=(b, n_s),
         in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, isb, bt: (ib,)),        # pos
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda ib, ih, isb, bt: (ib, ih, 0, 0)),     # q
-            pl.BlockSpec((1, ps, 1, hdp),
-                         lambda ib, ih, isb, bt: (bt[ib, isb], 0, ih, 0)),
-            pl.BlockSpec((1, ps, 1),
-                         lambda ib, ih, isb, bt: (bt[ib, isb], 0, ih)),
-            pl.BlockSpec((1, ps, 1, hdp),
-                         lambda ib, ih, isb, bt: (bt[ib, isb], 0, ih, 0)),
-            pl.BlockSpec((1, ps, 1),
-                         lambda ib, ih, isb, bt: (bt[ib, isb], 0, ih)),
+            pl.BlockSpec((1, kvh, g, hd),
+                         lambda ib, isb, bt, p: (ib, 0, 0, 0)),
+            *_page_specs(ps, kvh, hdp,
+                         lambda ib, isb, bt, p: bt[ib * n_s + isb]),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda ib, ih, isb, bt: (ib, ih, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),     # running max
-            pltpu.VMEM((g, 1), jnp.float32),     # running denominator
-            pltpu.VMEM((g, hd), jnp.float32),    # output accumulator
-        ],
+        out_specs=pl.BlockSpec((1, kvh, g, hd),
+                               lambda ib, isb, bt, p: (ib, 0, 0, 0)),
+        scratch_shapes=_scratch(kvh, g, hd),
     )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, n_s=n_s, bs=ps, scale=scale),
+    return pallas_call(
+        functools.partial(_paged_kernel, n_s=n_s, bs=ps, scale=hd ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(block_tables, pos, q, k_pages, k_scale_pages, v_pages, v_scale_pages)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(block_tables.reshape(-1), pos, q, k_pages, k_scale_pages, v_pages,
+      v_scale_pages)
 
 
 def _paged_verify_kernel(bt_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref,
                          vs_ref, out_ref, m_ref, l_ref, acc_ref, *, n_s,
                          bs, scale):
-    # grid = (B, KVH, T, n_s): window token t's query position is pos + t;
+    # grid = (B, T, n_s): window token t's query position is pos + t;
     # everything else is the shared single-token flash step, so cell
-    # (b, h, t) computes exactly what a single-token decode at pos + t
+    # (b, t) computes exactly what a single-token decode at pos + t
     # would (bit-exact vs a loop of kv4_paged_decode_attention calls)
     del bt_ref
-    _flash_step(pos_ref[0] + pl.program_id(2), pl.program_id(3), q_ref,
-                kq_ref, ks_ref, vq_ref, vs_ref, out_ref, m_ref, l_ref,
-                acc_ref, n_s=n_s, bs=bs, scale=scale)
+    _scan_step(pos_ref[pl.program_id(0)] + pl.program_id(1),
+               pl.program_id(2), lambda h: q_ref[0, 0, h],
+               _kv4(kq_ref, ks_ref, vq_ref, vs_ref), out_ref, m_ref, l_ref,
+               acc_ref, n_s=n_s, bs=bs, scale=scale)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def kv4_paged_verify_attention(
     q: jax.Array,             # (B, T, KVH, G, hd) — T window tokens/seq
     k_pages: jax.Array,       # (P, ps, KVH, hd//2) int8, packed nibbles
@@ -285,8 +264,6 @@ def kv4_paged_verify_attention(
     v_scale_pages: jax.Array, # (P, ps, KVH) f32
     block_tables: jax.Array,  # (B, Pmax) int32 — seq-order page ids
     pos: jax.Array,           # (B,) int32 — position of window token 0
-    *,
-    interpret: bool = True,
 ) -> jax.Array:
     """Multi-token decode attention for speculative verification.
 
@@ -298,49 +275,38 @@ def kv4_paged_verify_attention(
     (B, T, KVH, G, hd).
 
     The window axis is a grid dimension, not a wider query block: each
-    (b, h, t) grid cell replays the single-token kernel body with the
-    same block and dot shapes, which makes the output bit-exact against
-    T sequential :func:`kv4_paged_decode_attention` calls.
+    (b, t) grid cell replays the single-token kernel body with the same
+    block and dot shapes, which makes the output bit-exact against T
+    sequential :func:`kv4_paged_decode_attention` calls.
     """
     b, t, kvh, g, hd = q.shape
-    n_pages, ps, _, hdp = k_pages.shape
+    _, ps, _, hdp = k_pages.shape
     _, n_s = block_tables.shape
     assert hdp * 2 == hd, (hd, hdp)
-    scale = hd ** -0.5
+
+    def q_map(ib, it, isb, bt, p):
+        return (ib, it, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kvh, t, n_s),
+        num_scalar_prefetch=2,
+        grid=(b, t, n_s),
         in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, it, isb, bt: (ib,)),    # pos
-            pl.BlockSpec((1, 1, 1, g, hd),
-                         lambda ib, ih, it, isb, bt: (ib, it, ih, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hdp),
-                         lambda ib, ih, it, isb, bt: (bt[ib, isb], 0, ih, 0)),
-            pl.BlockSpec((1, ps, 1),
-                         lambda ib, ih, it, isb, bt: (bt[ib, isb], 0, ih)),
-            pl.BlockSpec((1, ps, 1, hdp),
-                         lambda ib, ih, it, isb, bt: (bt[ib, isb], 0, ih, 0)),
-            pl.BlockSpec((1, ps, 1),
-                         lambda ib, ih, it, isb, bt: (bt[ib, isb], 0, ih)),
+            pl.BlockSpec((1, 1, kvh, g, hd), q_map),
+            *_page_specs(ps, kvh, hdp,
+                         lambda ib, it, isb, bt, p: bt[ib * n_s + isb]),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, g, hd),
-                               lambda ib, ih, it, isb, bt: (ib, it, ih, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),     # running max
-            pltpu.VMEM((g, 1), jnp.float32),     # running denominator
-            pltpu.VMEM((g, hd), jnp.float32),    # output accumulator
-        ],
+        out_specs=pl.BlockSpec((1, 1, kvh, g, hd), q_map),
+        scratch_shapes=_scratch(kvh, g, hd),
     )
-    return pl.pallas_call(
-        functools.partial(_paged_verify_kernel, n_s=n_s, bs=ps, scale=scale),
+    return pallas_call(
+        functools.partial(_paged_verify_kernel, n_s=n_s, bs=ps,
+                          scale=hd ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, kvh, g, hd), q.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-    )(block_tables, pos, q, k_pages, k_scale_pages, v_pages, v_scale_pages)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )(block_tables.reshape(-1), pos, q, k_pages, k_scale_pages, v_pages,
+      v_scale_pages)
 
 
 def _tiered_paged_kernel(bt_ref, tt_ref, pos_ref, q_ref, kq_ref, ks_ref,
@@ -349,20 +315,24 @@ def _tiered_paged_kernel(bt_ref, tt_ref, pos_ref, q_ref, kq_ref, ks_ref,
     # per-page tier routing: the index maps already DMA'd the right slab
     # block (the other slab's block is its null page); the body dequantizes
     # both candidates and selects by the prefetched tier id. On a tier-0
-    # page the selected f32 values are elementwise identical to what
-    # _flash_step computes, so the shared core produces identical bits.
-    tier = tt_ref[pl.program_id(0), pl.program_id(2)]
-    k4 = _dequant4_block(kq_ref, ks_ref, bs)
-    v4 = _dequant4_block(vq_ref, vs_ref, bs)
-    k2 = _dequant2_block(k2q_ref, k2s_ref, bs)
-    v2 = _dequant2_block(v2q_ref, v2s_ref, bs)
-    k = jnp.where(tier == 1, k2, k4)
-    v = jnp.where(tier == 1, v2, v4)
-    _flash_core(pos_ref[0], pl.program_id(2), q_ref, k, v, out_ref,
-                m_ref, l_ref, acc_ref, n_s=n_s, bs=bs, scale=scale)
+    # page the selected f32 values are elementwise identical to what the
+    # KV4 kernel computes, so the shared scan step produces identical bits.
+    del bt_ref
+    ib, isb = pl.program_id(0), pl.program_id(1)
+    demoted = tt_ref[ib * n_s + isb] == 1
+
+    def kv_of(h):
+        k = jnp.where(demoted, _dequant(k2q_ref, k2s_ref, h, 2),
+                      _dequant(kq_ref, ks_ref, h, 4))
+        v = jnp.where(demoted, _dequant(v2q_ref, v2s_ref, h, 2),
+                      _dequant(vq_ref, vs_ref, h, 4))
+        return k, v
+
+    _scan_step(pos_ref[ib], isb, lambda h: q_ref[0, h], kv_of, out_ref,
+               m_ref, l_ref, acc_ref, n_s=n_s, bs=bs, scale=scale)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def kv_tiered_paged_decode_attention(
     q: jax.Array,              # (B, KVH, G, hd) — grouped query heads
     k_pages: jax.Array,        # (P, ps, KVH, hd//2) int8, packed nibbles
@@ -376,8 +346,6 @@ def kv_tiered_paged_decode_attention(
     block_tables: jax.Array,   # (B, Pmax) int32 — seq-order page ids
     tier_tables: jax.Array,    # (B, Pmax) int32 — per-page tier (0/1)
     pos: jax.Array,            # (B,) int32 — current position (inclusive)
-    *,
-    interpret: bool = True,
 ) -> jax.Array:
     """Mixed-tier decode attention over the KV4 + KV2 page slabs.
 
@@ -393,55 +361,39 @@ def kv_tiered_paged_decode_attention(
     original scales (clamp error bound in docs/format.md).
     """
     b, kvh, g, hd = q.shape
-    n_pages, ps, _, hdp = k_pages.shape
+    _, ps, _, hdp = k_pages.shape
     _, n_s = block_tables.shape
     assert hdp * 2 == hd, (hd, hdp)
     assert k2_pages.shape[-1] * 4 == hd, (hd, k2_pages.shape)
-    scale = hd ** -0.5
 
-    def kv4_map(ib, ih, isb, bt, tt):
-        return (jnp.where(tt[ib, isb] == 1, 0, bt[ib, isb]), 0, ih, 0)
+    def kv4_page(ib, isb, bt, tt, p):
+        i = ib * n_s + isb
+        return jnp.where(tt[i] == 1, 0, bt[i])
 
-    def kv4_smap(ib, ih, isb, bt, tt):
-        return (jnp.where(tt[ib, isb] == 1, 0, bt[ib, isb]), 0, ih)
-
-    def kv2_map(ib, ih, isb, bt, tt):
-        return (jnp.where(tt[ib, isb] == 1, bt[ib, isb], 0), 0, ih, 0)
-
-    def kv2_smap(ib, ih, isb, bt, tt):
-        return (jnp.where(tt[ib, isb] == 1, bt[ib, isb], 0), 0, ih)
+    def kv2_page(ib, isb, bt, tt, p):
+        i = ib * n_s + isb
+        return jnp.where(tt[i] == 1, bt[i], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kvh, n_s),
+        num_scalar_prefetch=3,
+        grid=(b, n_s),
         in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, isb, bt, tt: (ib,)),    # pos
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda ib, ih, isb, bt, tt: (ib, ih, 0, 0)),  # q
-            pl.BlockSpec((1, ps, 1, hdp), kv4_map),                   # k_q
-            pl.BlockSpec((1, ps, 1), kv4_smap),                       # k_s
-            pl.BlockSpec((1, ps, 1, hdp), kv4_map),                   # v_q
-            pl.BlockSpec((1, ps, 1), kv4_smap),                       # v_s
-            pl.BlockSpec((1, ps, 1, hd // 4), kv2_map),               # k2_q
-            pl.BlockSpec((1, ps, 1), kv2_smap),                       # k2_s
-            pl.BlockSpec((1, ps, 1, hd // 4), kv2_map),               # v2_q
-            pl.BlockSpec((1, ps, 1), kv2_smap),                       # v2_s
+            pl.BlockSpec((1, kvh, g, hd),
+                         lambda ib, isb, bt, tt, p: (ib, 0, 0, 0)),
+            *_page_specs(ps, kvh, hdp, kv4_page),
+            *_page_specs(ps, kvh, hd // 4, kv2_page),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda ib, ih, isb, bt, tt: (ib, ih, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),     # running max
-            pltpu.VMEM((g, 1), jnp.float32),     # running denominator
-            pltpu.VMEM((g, hd), jnp.float32),    # output accumulator
-        ],
+        out_specs=pl.BlockSpec((1, kvh, g, hd),
+                               lambda ib, isb, bt, tt, p: (ib, 0, 0, 0)),
+        scratch_shapes=_scratch(kvh, g, hd),
     )
-    return pl.pallas_call(
-        functools.partial(_tiered_paged_kernel, n_s=n_s, bs=ps, scale=scale),
+    return pallas_call(
+        functools.partial(_tiered_paged_kernel, n_s=n_s, bs=ps,
+                          scale=hd ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(block_tables, tier_tables, pos, q, k_pages, k_scale_pages,
-      v_pages, v_scale_pages, k2_pages, k2_scale_pages,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(block_tables.reshape(-1), tier_tables.reshape(-1), pos, q, k_pages,
+      k_scale_pages, v_pages, v_scale_pages, k2_pages, k2_scale_pages,
       v2_pages, v2_scale_pages)

@@ -3,8 +3,8 @@
 ``sparqle_linear`` is the framework's quantized-linear entry point. It hides
 tile padding, backend selection and the encode step:
 
-  * ``backend='pallas'``  — Pallas kernels (interpret=True on CPU; the real
-    TPU target when run on TPU devices);
+  * ``backend='pallas'``  — Pallas kernels (compiled on TPU, interpreted on
+    CPU — ``kernels.pallas_call`` decides from the lowering platform);
   * ``backend='xla'``     — the pure-XLA dual-pass path
     (``core.sparse_matmul``), used inside pjit'd distributed graphs.
 
@@ -29,7 +29,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.packing import pack_nibbles
 from repro.core.quantize import QuantizedTensor, quantize_activations
 from repro.core.sparqle import SparqleActivation, encode, tile_population
-from repro.distributed.tp import shard_map_compat
 from repro.kernels.quant_matmul import quant_matmul
 from repro.kernels.sparqle_matmul import (
     DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, sparqle_matmul,
@@ -44,7 +43,7 @@ def _pad_to(x: jax.Array, mult: tuple) -> jax.Array:
 
 
 def _padded_kernel_call(q, w_q, a_scale, w_scale, *, wire_format, msb_skip,
-                        bm, bn, bk, interpret, acc_out=False):
+                        bm, bn, bk, acc_out=False):
     """Encode int8 activations, tile-pad, dispatch the kernel, un-pad.
 
     Shared by the single-device and shard_map'd entry points, so sharded
@@ -64,11 +63,11 @@ def _padded_kernel_call(q, w_q, a_scale, w_scale, *, wire_format, msb_skip,
     if wire_format == "packed":
         out = sparqle_matmul_packed(
             pack_nibbles(lsb), pack_nibbles(msb), pop, wq, asc, wsc,
-            bm=bm, bn=bn, bk=bk, interpret=interpret, msb_skip=msb_skip,
+            bm=bm, bn=bn, bk=bk, msb_skip=msb_skip,
             acc_out=acc_out)
     else:
         out = sparqle_matmul(lsb, msb, pop, wq, asc, wsc,
-                             bm=bm, bn=bn, bk=bk, interpret=interpret,
+                             bm=bm, bn=bn, bk=bk,
                              msb_skip=msb_skip, acc_out=acc_out)
     return out[:m, :n_out]
 
@@ -86,7 +85,6 @@ def sparqle_linear(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
 ) -> jax.Array:
     """Quantize -> (clip) -> decompose -> dual-pass matmul. x: (..., K).
 
@@ -129,7 +127,7 @@ def sparqle_linear(
     # pallas path: pad everything to tile multiples
     out = _padded_kernel_call(q, w.q, qa.scale, w.scale,
                               wire_format=wire_format, msb_skip=msb_skip,
-                              bm=bm, bn=bn, bk=bk, interpret=interpret)
+                              bm=bm, bn=bn, bk=bk)
     return out.reshape(*orig[:-1], n_out).astype(x.dtype)
 
 
@@ -148,7 +146,6 @@ def sparqle_linear_sharded(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
 ) -> jax.Array:
     """:func:`sparqle_linear` with the weight partitioned on a mesh axis.
 
@@ -181,8 +178,7 @@ def sparqle_linear_sharded(
                 q = apply_clipping(q, mask, clip_l, clip_h)
             return _padded_kernel_call(
                 q, wq_l, qa.scale, wsc_l, wire_format=wire_format,
-                msb_skip=msb_skip, bm=bm, bn=bn, bk=bk,
-                interpret=interpret)
+                msb_skip=msb_skip, bm=bm, bn=bn, bk=bk)
 
         in_specs = (P(), P(None, axis), P(None, axis),
                     P() if has_clip else None)
@@ -199,7 +195,7 @@ def sparqle_linear_sharded(
             acc = _padded_kernel_call(
                 q, wq_l, qa.scale, wsc, wire_format=wire_format,
                 msb_skip=msb_skip, bm=bm, bn=bn, bk=bk,
-                interpret=interpret, acc_out=True)
+                acc_out=True)
             acc = jax.lax.psum(acc, axis)        # ONE reduction, int32
             return (acc.astype(jnp.float32)
                     * qa.scale.reshape(-1, 1).astype(jnp.float32)
@@ -209,7 +205,8 @@ def sparqle_linear_sharded(
                     P(axis) if has_clip else None)
         out_specs = P()
 
-    fn = shard_map_compat(body, mesh, in_specs, out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out = fn(x2, w.q.astype(jnp.int8), w.scale.reshape(1, -1),
              col_mask)
     return out.reshape(*orig[:-1], n_out).astype(x.dtype)
@@ -222,7 +219,6 @@ def dense_quant_linear(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
 ) -> jax.Array:
     """Baseline dense W4A8 linear (no SPARQLe decomposition)."""
     orig = x.shape
@@ -234,7 +230,6 @@ def dense_quant_linear(
     wq = _pad_to(w.q.astype(jnp.int8), (bk, bn))
     asc = _pad_to(qa.scale.reshape(-1, 1).astype(jnp.float32), (bm, 1))
     wsc = _pad_to(w.scale.reshape(1, -1).astype(jnp.float32), (1, bn))
-    out = quant_matmul(a, wq, asc, wsc, bm=bm, bn=bn, bk=bk,
-                       interpret=interpret)
+    out = quant_matmul(a, wq, asc, wsc, bm=bm, bn=bn, bk=bk)
     out = out[:m, :n_out]
     return out.reshape(*orig[:-1], n_out).astype(x.dtype)

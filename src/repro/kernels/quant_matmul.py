@@ -17,8 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
+from repro.kernels import pallas_call
 from repro.kernels.sparqle_matmul import DEFAULT_BK, DEFAULT_BM, DEFAULT_BN
 
 
@@ -41,7 +40,7 @@ def _kernel(a_ref, w_ref, ascale_ref, wscale_ref, out_ref, acc_ref, *, n_k: int)
             * wscale_ref[...].astype(jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
 def quant_matmul(
     a: jax.Array,          # (M, K) int8 activations
     w: jax.Array,          # (K, N) int8 (int4 payload)
@@ -51,14 +50,13 @@ def quant_matmul(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
 ) -> jax.Array:
     m, k = a.shape
     k2, n = w.shape
     assert k == k2 and m % bm == 0 and n % bn == 0 and k % bk == 0
 
     n_k = k // bk
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel, n_k=n_k),
         grid=(m // bm, n // bn, n_k),
         in_specs=[
@@ -70,7 +68,6 @@ def quant_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, w, act_scale, w_scale)

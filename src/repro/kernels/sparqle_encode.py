@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.packing import PBM_WORD_BITS, pack_nibbles, pack_pbm
+from repro.kernels import pallas_call
 
 # Smallest normal f32: anything below (zero or denormal scales) is treated
 # as a degenerate all-zero token and divided by 1 instead.
@@ -65,20 +66,19 @@ def _kernel_packed(x_ref, scale_ref, lsb_ref, msb_ref, pbm_ref, pop_ref):
     pop_ref[0, 0] = jnp.sum(pbm.astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bk"))
 def sparqle_encode(
     x: jax.Array,       # (M, K) f32/bf16 pre-quantization outputs
     scale: jax.Array,   # (M, 1) f32 per-token scales
     *,
     bm: int = 128,
     bk: int = 128,
-    interpret: bool = True,
 ):
     """Returns (lsb4, msb4, pbm, tile_pop) with tile_pop (M/bm, K/bk)."""
     m, k = x.shape
     assert m % bm == 0 and k % bk == 0, (x.shape, bm, bk)
     grid = (m // bm, k // bk)
-    return pl.pallas_call(
+    return pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
@@ -97,18 +97,16 @@ def sparqle_encode(
             jax.ShapeDtypeStruct((m, k), jnp.bool_),
             jax.ShapeDtypeStruct((m // bm, k // bk), jnp.int32),
         ],
-        interpret=interpret,
     )(x, scale)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bk"))
 def sparqle_encode_packed(
     x: jax.Array,       # (M, K) f32/bf16 pre-quantization outputs
     scale: jax.Array,   # (M, 1) f32 per-token scales
     *,
     bm: int = 128,
     bk: int = 128,
-    interpret: bool = True,
 ):
     """Wire-format drain: (lsb4_packed (M, K/2), msb4_packed (M, K/2),
     pbm_words (M, K/32), tile_pop (M/bm, K/bk)).
@@ -123,7 +121,7 @@ def sparqle_encode_packed(
     grid = (m // bm, k // bk)
     hbk = bk // 2
     nw = bk // PBM_WORD_BITS
-    return pl.pallas_call(
+    return pallas_call(
         _kernel_packed,
         grid=grid,
         in_specs=[
@@ -142,5 +140,4 @@ def sparqle_encode_packed(
             jax.ShapeDtypeStruct((m, k // PBM_WORD_BITS), jnp.uint32),
             jax.ShapeDtypeStruct((m // bm, k // bk), jnp.int32),
         ],
-        interpret=interpret,
     )(x, scale)

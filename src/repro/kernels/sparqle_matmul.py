@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import unpack_nibbles
-from repro.kernels import CompilerParams as _CompilerParams
+from repro.kernels import pallas_call
 
 
 DEFAULT_BM = 128
@@ -171,7 +171,7 @@ def _kernel_packed_draft(lsbp_ref, w_ref, ascale_ref, wscale_ref, out_ref,
 
 
 def _call(kernel, grid, act_specs, act_args, w, act_scale, w_scale,
-          tile_pop, m, n, bm, bn, bk, n_k, interpret, msb_skip=False,
+          tile_pop, m, n, bm, bn, bk, n_k, msb_skip=False,
           draft_kernel=None, acc_out=False):
     if msb_skip:
         # draft dispatch: ONLY the LSB plane is an operand — the MSB
@@ -184,7 +184,7 @@ def _call(kernel, grid, act_specs, act_args, w, act_scale, w_scale,
             *act_specs,                                            # lsb, msb
         ]
         args = (tile_pop, *act_args, w, act_scale, w_scale)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(kernel, n_k=n_k, acc_out=acc_out),
         grid=grid,
         in_specs=[
@@ -197,15 +197,14 @@ def _call(kernel, grid, act_specs, act_args, w, act_scale, w_scale,
         out_shape=jax.ShapeDtypeStruct(
             (m, n), jnp.int32 if acc_out else jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*args)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bn", "bk", "interpret", "msb_skip", "acc_out"))
+    static_argnames=("bm", "bn", "bk", "msb_skip", "acc_out"))
 def sparqle_matmul(
     lsb4: jax.Array,       # (M, K) int8 in [0, 15]
     msb4: jax.Array,       # (M, K) int8 in [-8, 7]
@@ -217,7 +216,6 @@ def sparqle_matmul(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
     msb_skip: bool = False,
     acc_out: bool = False,
 ) -> jax.Array:
@@ -239,14 +237,14 @@ def sparqle_matmul(
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),      # msb4
     ]
     return _call(_kernel, grid, act_specs, (lsb4, msb4), w, act_scale,
-                 w_scale, tile_pop, m, n, bm, bn, bk, n_k, interpret,
+                 w_scale, tile_pop, m, n, bm, bn, bk, n_k,
                  msb_skip=msb_skip, draft_kernel=_kernel_draft,
                  acc_out=acc_out)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bn", "bk", "interpret", "msb_skip", "acc_out"))
+    static_argnames=("bm", "bn", "bk", "msb_skip", "acc_out"))
 def sparqle_matmul_packed(
     lsb4_packed: jax.Array,  # (M, K/2) int8 — two LSB nibbles per byte
     msb4_packed: jax.Array,  # (M, K/2) int8 — two MSB nibbles per byte
@@ -258,7 +256,6 @@ def sparqle_matmul_packed(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
     msb_skip: bool = False,
     acc_out: bool = False,
 ) -> jax.Array:
@@ -292,6 +289,6 @@ def sparqle_matmul_packed(
     ]
     return _call(_kernel_packed, grid, act_specs,
                  (lsb4_packed, msb4_packed), w, act_scale, w_scale,
-                 tile_pop, m, n, bm, bn, bk, n_k, interpret,
+                 tile_pop, m, n, bm, bn, bk, n_k,
                  msb_skip=msb_skip, draft_kernel=_kernel_packed_draft,
                  acc_out=acc_out)

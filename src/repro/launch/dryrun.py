@@ -115,8 +115,6 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
         t_compile = time.time() - t0 - t_lower
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax < 0.6 returns [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     t1 = time.time()
     st = analyze(compiled.as_text())

@@ -10,12 +10,20 @@ per step), composing data parallelism over ICI within a pod.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes) -> jax.sharding.Mesh:
+    # Auto axes: shardings propagate through GSPMD and shard_map as the
+    # constrain()/spec_for() rule tables expect (make_mesh defaults to
+    # Explicit axes, which reject with_sharding_constraint on them)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_smoke_mesh(*, data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -31,4 +39,4 @@ def make_smoke_mesh(*, data: int = 1, model: int = 1) -> jax.sharding.Mesh:
             f"{need} (it must be set in the environment BEFORE jax "
             f"initializes — the multi-device CI lane and "
             f"tests/conftest.py's `mesh` fixture rely on this).")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _make_mesh((data, model), ("data", "model"))

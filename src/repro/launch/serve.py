@@ -33,7 +33,9 @@ from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed.sharding import mesh_context
 from repro.launch import steps as S
 from repro.launch.mesh import make_smoke_mesh
+from repro.launch.runtime import device_label, use_compile_cache
 from repro.models import model as M
+from repro.models.qschema import init_served_params
 from repro.models.registry import get_config
 from repro.models.schema import init_params
 from repro.models.schema_builder import build_schema
@@ -61,13 +63,15 @@ def _legacy_serve(cfg, qparams, batch, plen, args) -> None:
     gen = jnp.stack(out, 1)
     print(f"generated {gen.shape} tokens; "
           f"prefill {t_prefill*1e3:.0f} ms, "
-          f"{t_decode*1e3:.1f} ms/token (CPU interpret timings)")
+          f"{t_decode*1e3:.1f} ms/token (timings on {device_label()})")
 
 
-def _engine_serve(cfg, qparams, prompts, args, serve_mesh=None):
-    from repro.serving import (Engine, PoolConfig, SamplingParams,
-                               SchedulerConfig, SpecConfig,
-                               SpeculativeEngine)
+def make_engine(cfg, qparams, args, serve_mesh=None):
+    """The paged engine ``args`` describe: a pool that holds the whole
+    batch (unless ``--n-pages``), scheduler limits from the engine knobs,
+    and the speculative engine when ``--spec-gamma`` > 0."""
+    from repro.serving import (Engine, PoolConfig, SchedulerConfig,
+                               SpecConfig, SpeculativeEngine)
     gamma = getattr(args, "spec_gamma", 0)
     data_ways = 1
     if serve_mesh is not None:
@@ -95,10 +99,14 @@ def _engine_serve(cfg, qparams, prompts, args, serve_mesh=None):
             max_pages_per_seq=pages_per_seq),
         mesh=serve_mesh, slos=slos)
     if gamma > 0:
-        eng = SpeculativeEngine(cfg, qparams, spec=SpecConfig(gamma=gamma),
-                                **kw)
-    else:
-        eng = Engine(cfg, qparams, **kw)
+        return SpeculativeEngine(cfg, qparams, spec=SpecConfig(gamma=gamma),
+                                 **kw)
+    return Engine(cfg, qparams, **kw)
+
+
+def _engine_serve(cfg, qparams, prompts, args, serve_mesh=None):
+    from repro.serving import SamplingParams
+    eng = make_engine(cfg, qparams, args, serve_mesh)
     if getattr(args, "attribute", False):
         attr = eng.attribute_steps()
         for phase, c in sorted(attr.summary().items()):
@@ -124,7 +132,7 @@ def _engine_serve(cfg, qparams, prompts, args, serve_mesh=None):
     spars = [s["act_sparsity"] for s in stats]
     print(f"engine: {len(handles)} requests, {n_tok} tokens in "
           f"{wall:.2f} s ({n_tok / wall:.1f} tok/s, "
-          f"{eng.steps} steps; CPU interpret timings)")
+          f"{eng.steps} steps; timings on {device_label()})")
     print(f"  TTFT  mean {np.mean(ttft)*1e3:.0f} ms  "
           f"p95 {np.percentile(ttft, 95)*1e3:.0f} ms")
     if tpot:
@@ -152,7 +160,7 @@ def _engine_serve(cfg, qparams, prompts, args, serve_mesh=None):
     return eng
 
 
-def main(argv=None) -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -206,7 +214,47 @@ def main(argv=None) -> None:
                          "--xla_force_host_platform_device_count=N). "
                          "Token streams are bit-exact vs the default "
                          "single-device engine (docs/sharding.md).")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def served_params(cfg, args):
+    """The served (quantized) parameter tree ``args`` describe: seeded
+    random weights built leaf by leaf, so the float tree never exists
+    (``qschema.init_served_params``), or a float checkpoint (``--ckpt``)
+    restored whole and then quantized."""
+    quant = dict(w_bits=cfg.w_bits, k_percent=args.k_percent,
+                 clip_l=args.clip_l, clip_h=args.clip_h, mode=args.mode,
+                 enable_clipping=not args.no_clip,
+                 tile_k=16 if args.smoke else 128)
+    schema = build_schema(cfg)
+    key = jax.random.PRNGKey(args.seed)
+    if not args.ckpt:
+        return init_served_params(schema, key, **quant)
+    params = init_params(schema, key)
+    latest = store.latest_step(args.ckpt)
+    params = store.restore(args.ckpt, latest, {"params": params})["params"]
+    return quantize_model_params(params, **quant)
+
+
+def prompt_batch(cfg, args):
+    """(prompt tokens (batch, prompt_len), model batch dict): seeded
+    synthetic prompts, with stub patch embeddings for a VLM."""
+    data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=args.prompt_len,
+                                  global_batch=args.batch, seed=args.seed))
+    prompts = jnp.asarray(data.batch_at(0)["tokens"])
+    if cfg.family == "vlm":
+        return prompts, {
+            "patches": jax.random.normal(
+                jax.random.PRNGKey(1),
+                (args.batch, cfg.n_prefix, cfg.d_model)).astype(cfg.cdtype),
+            "tokens": prompts[:, :args.prompt_len - cfg.n_prefix]}
+    return prompts, {"tokens": prompts}
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    use_compile_cache()
+    print(f"devices: {device_label()}")
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family == "encoder":
@@ -236,33 +284,9 @@ def main(argv=None) -> None:
     mesh = make_smoke_mesh()
 
     with mesh_context(mesh):
-        params = init_params(build_schema(cfg), jax.random.PRNGKey(args.seed))
-        if args.ckpt:
-            latest = store.latest_step(args.ckpt)
-            params = store.restore(args.ckpt, latest,
-                                   {"params": params})["params"]
-        tile_k = 16 if args.smoke else 128
-        qparams = quantize_model_params(
-            params, w_bits=cfg.w_bits, k_percent=args.k_percent,
-            clip_l=args.clip_l, clip_h=args.clip_h, mode=args.mode,
-            enable_clipping=not args.no_clip, tile_k=tile_k)
-
-        data = SyntheticLM(DataConfig(vocab=cfg.vocab,
-                                      seq_len=args.prompt_len,
-                                      global_batch=args.batch,
-                                      seed=args.seed))
-        prompts = jnp.asarray(data.batch_at(0)["tokens"])
-        if cfg.family == "vlm":
-            batch = {
-                "patches": jax.random.normal(
-                    jax.random.PRNGKey(1),
-                    (args.batch, cfg.n_prefix, cfg.d_model)).astype(
-                        cfg.cdtype),
-                "tokens": prompts[:, :args.prompt_len - cfg.n_prefix]}
-            plen = args.prompt_len
-        else:
-            batch = {"tokens": prompts}
-            plen = args.prompt_len
+        qparams = served_params(cfg, args)
+        prompts, batch = prompt_batch(cfg, args)
+        plen = args.prompt_len
 
         if args.legacy:
             _legacy_serve(cfg, qparams, batch, plen, args)
@@ -284,7 +308,8 @@ def main(argv=None) -> None:
                 print(f"  chrome trace     -> {args.trace_out}")
 
         # achieved sub-precision sparsity of the hidden stream
-        hidden = M.forward_hidden(cfg, qparams, batch)
+        hidden = jax.jit(lambda p, b: M.forward_hidden(cfg, p, b))(
+            qparams, batch)
         q = quantize_activations(hidden.reshape(-1, hidden.shape[-1]),
                                  bits=8, per_token=True).q
         s = float(subprecision_sparsity(q))

@@ -270,11 +270,8 @@ def abstract_like(tree: Any) -> Any:
     runtime executes).
     """
     def _leaf(x: Any) -> jax.ShapeDtypeStruct:
-        sharding = getattr(x, "sharding", None)
-        try:
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
-        except TypeError:
-            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=getattr(x, "sharding", None))
     return jax.tree_util.tree_map(_leaf, tree)
 
 
@@ -307,7 +304,7 @@ def make_engine_prefill_chunk(cfg: ModelConfig, *,
 
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import no_mesh
-    from repro.distributed.tp import shard_map_compat, tp_scope
+    from repro.distributed.tp import tp_scope
     lcfg, mways, daxis = _mesh_layout(cfg, mesh)
 
     def body(params, pool, tokens, start, valid, table):
@@ -329,8 +326,8 @@ def make_engine_prefill_chunk(cfg: ModelConfig, *,
 
     tel_specs = {"sparsity": P(), "layer_sparsity": P(None),
                  "layer_wire_bytes": P(None), "layer_dense_bytes": P(None)}
-    return shard_map_compat(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(param_specs, pool_specs, P(), P(), P(), P(daxis, None)),
         out_specs=(P(), pool_specs, tel_specs))
 
@@ -394,7 +391,7 @@ def make_engine_decode(cfg: ModelConfig, *, msb_skip: bool = False,
 
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import no_mesh
-    from repro.distributed.tp import shard_map_compat, tp_scope
+    from repro.distributed.tp import tp_scope
     lcfg, mways, daxis = _mesh_layout(cfg, mesh)
 
     def body(params, pool, token, pos, tables):
@@ -407,8 +404,8 @@ def make_engine_decode(cfg: ModelConfig, *, msb_skip: bool = False,
     tel_specs = ({"sparsity": B, "layer_sparsity": LB,
                   "layer_wire_bytes": LB, "layer_dense_bytes": LB}
                  if with_telemetry else {})
-    return shard_map_compat(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(param_specs, pool_specs, B, B, P(daxis, None)),
         out_specs=(P(daxis, None), pool_specs, tel_specs))
 
@@ -437,7 +434,7 @@ def make_engine_verify_window(cfg: ModelConfig, *,
 
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import no_mesh
-    from repro.distributed.tp import shard_map_compat, tp_scope
+    from repro.distributed.tp import tp_scope
     lcfg, mways, daxis = _mesh_layout(cfg, mesh)
 
     def body(params, pool, tokens, pos, tables):
@@ -448,8 +445,8 @@ def make_engine_verify_window(cfg: ModelConfig, *,
     B, LB = P(daxis), P(None, daxis)
     tel_specs = {"sparsity": B, "layer_sparsity": LB,
                  "layer_wire_bytes": LB, "layer_dense_bytes": LB}
-    return shard_map_compat(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(param_specs, pool_specs, P(daxis, None), B,
                   P(daxis, None)),
         out_specs=(P(daxis, None, None), pool_specs, tel_specs))

@@ -232,15 +232,9 @@ def moe_ffn_dist(
     spec_x = P(data_axes if len(data_axes) > 1 else data_axes[0])
     manual = frozenset(data_axes) | {"model"}
     in_specs = (spec_x, P(), wspec(w_gate), wspec(w_up), wspec(w_down))
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(
-            local, mesh=mesh, axis_names=manual,
-            in_specs=in_specs, out_specs=spec_x, check_vma=False)
-    else:  # jax < 0.6: experimental API; manual axes = complement of auto
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(
-            local, mesh=mesh, in_specs=in_specs, out_specs=spec_x,
-            check_rep=False, auto=frozenset(mesh.axis_names) - manual)
+    fn = jax.shard_map(
+        local, mesh=mesh, axis_names=manual,
+        in_specs=in_specs, out_specs=spec_x, check_vma=False)
     return fn(x, w_router, w_gate, w_up, w_down)
 
 

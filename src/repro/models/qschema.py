@@ -7,6 +7,11 @@ the int8-container weight, per-output-channel scales, column-importance
 mask and clipping constants. From that tree the dry-run derives
 ShapeDtypeStructs and NamedShardings without allocating any memory — this
 is how a 671B-param served model lowers on a laptop.
+
+``init_served_params`` is the concrete counterpart: it builds the served
+tree of seeded random weights leaf by leaf, so that a model whose float
+tree would not fit the device never holds more than one float layer slice
+of one projection at a time.
 """
 from __future__ import annotations
 
@@ -16,10 +21,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
-from repro.core.qlinear import SparqleLinear, is_quantizable
+from repro.core.qlinear import SparqleLinear, is_quantizable, leaf_plan
 from repro.core.quantize import QuantizedTensor
 from repro.distributed.sharding import spec_for
-from repro.models.schema import ParamSpec
+from repro.models.schema import (ParamSpec, Schema, _map_schema, init_layer,
+                                 init_leaf, leaf_keys)
 
 
 def _quantize_spec(spec: ParamSpec, path: str) -> SparqleLinear:
@@ -95,3 +101,46 @@ def tree_shardings(tree, mesh: Mesh) -> Any:
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, spec_for(s.axes, s.shape, mesh)),
         tree, is_leaf=_is_spec)
+
+
+def init_served_params(schema: Schema, key: jax.Array,
+                       **quant_kw) -> Dict[str, Any]:
+    """``quantize_model_params(init_params(schema, key), **quant_kw)``,
+    built without ever holding the float tree.
+
+    Each quantizable leaf is made in one jitted init -> quantize program;
+    a layer-stacked leaf is made one layer slice per call, written into a
+    donated stacked buffer, so only the served tree persists on the device.
+    A slice's values are those of the whole leaf's random draw
+    (``schema.init_layer``), computed without the rest of the draw. Other
+    leaves (embeddings, norms, biases) are built as ``init_params`` builds
+    them.
+    """
+    keys = leaf_keys(schema, key)
+
+    def build(path: str, spec: ParamSpec):
+        if not is_quantizable(path, _Probe(spec)):
+            return init_leaf(keys[path], spec)
+        quantize, stacked = leaf_plan(path, len(spec.shape), **quant_kw)
+        if not stacked:
+            return jax.jit(lambda k: quantize(init_leaf(k, spec)))(
+                keys[path])
+
+        def one_layer(k, i):
+            return quantize(init_layer(k, spec, i))
+
+        def write(buf, k, i):
+            return jax.tree_util.tree_map(
+                lambda b, x: jax.lax.dynamic_update_index_in_dim(b, x, i, 0),
+                buf, one_layer(k, i))
+
+        n_layers = spec.shape[0]
+        buf = jax.tree_util.tree_map(
+            lambda a: jnp.zeros((n_layers,) + a.shape, a.dtype),
+            jax.eval_shape(one_layer, keys[path], 0))
+        write = jax.jit(write, donate_argnums=0)
+        for i in range(n_layers):
+            buf = write(buf, keys[path], jnp.int32(i))
+        return buf
+
+    return _map_schema(schema, build)

@@ -20,7 +20,8 @@ bytes and per-kind collective bytes land in the metrics registry:
 
 At read time (``Engine._refresh_gauges``) the static costs join the
 measured ``serving_step_seconds`` means into roofline-style utilization
-against ``costmodel.HardwareConfig`` system peaks:
+against the peaks of the device the steps run on
+(``costmodel.hardware_for``):
 
   * ``serving_roofline_achieved_flops_per_s{phase=}`` and
     ``serving_roofline_achieved_bytes_per_s{phase=}``,
@@ -103,12 +104,11 @@ class StepAttribution:
     sharing an ``Observability`` safe.
     """
 
-    def __init__(self, obs, hw=None,
+    def __init__(self, obs, hw,
                  latency_drift_factor: float = DEFAULT_LATENCY_DRIFT_FACTOR,
                  wire_drift_tol: float = DEFAULT_WIRE_DRIFT_TOL):
-        from repro.core.costmodel import HardwareConfig
         self.obs = obs
-        self.hw = hw or HardwareConfig()
+        self.hw = hw      # costmodel.HardwareConfig: the roofline peaks
         self.latency_drift_factor = float(latency_drift_factor)
         self.wire_drift_tol = float(wire_drift_tol)
         self._phases: Dict[str, _PhaseState] = {}

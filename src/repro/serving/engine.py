@@ -275,10 +275,15 @@ class Engine:
 
         ``hw`` (``costmodel.HardwareConfig``) sets the roofline peaks
         and the cost-model latency predictor's substrate; defaults to
-        the paper's reference config. Returns the ``StepAttribution``.
+        the published peaks of the device the engine runs on
+        (``costmodel.hardware_for``), which raises for a device without
+        a table entry. Returns the ``StepAttribution``.
         """
+        from repro.core.costmodel import hardware_for
         from repro.obs.attribution import StepAttribution
         if self._attr is None:
+            if hw is None:
+                hw = hardware_for(jax.devices()[0].device_kind)
             self._attr = StepAttribution(self.obs, hw=hw)
         sds = jax.ShapeDtypeStruct
         params_a, pool_a = self._attr_abstract_args()

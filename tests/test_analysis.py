@@ -212,12 +212,11 @@ def _toy_step(fn, *args, kind="decode", mesh=False, name=None):
 
 def _shmap(fn, n_out=1):
     """Wrap fn in a 1x1 shard_map so collectives trace as primitives."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_smoke_mesh
     outs = P() if n_out == 1 else tuple(P() for _ in range(n_out))
-    return shard_map(fn, mesh=mesh, in_specs=P(), out_specs=outs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=make_smoke_mesh(), in_specs=P(),
+                         out_specs=outs, check_vma=False)
 
 
 def test_jxp002_row_psum_discipline():

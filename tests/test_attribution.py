@@ -11,6 +11,7 @@ import jax
 import pytest
 
 from repro.configs.base import ModelConfig
+from repro.core.costmodel import HardwareConfig
 from repro.core.qlinear import quantize_model_params
 from repro.launch import hlo_analysis as H
 from repro.models.schema import init_params
@@ -22,6 +23,9 @@ from repro.obs.slo import (SLO, SLOMonitor, SlidingWindow, parse_slo,
 from repro.obs.validate import validate_attribution
 from repro.serving import (Engine, PoolConfig, SamplingParams,
                            SchedulerConfig, SpecConfig, SpeculativeEngine)
+
+# a CPU has no entry in costmodel.DEVICE_PEAKS: pass roofline peaks
+CPU_PEAKS = HardwareConfig()
 
 CFG = ModelConfig(name="tiny-attr", family="transformer", n_layers=2,
                   d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
@@ -114,7 +118,7 @@ def test_no_entry_fails_strict():
 @pytest.mark.slow
 def test_attribute_real_steps_single_device():
     eng = _engine()
-    attr = eng.attribute_steps()
+    attr = eng.attribute_steps(hw=CPU_PEAKS)
     assert set(attr.phases()) == {"prefill", "decode"}
     for phase in attr.phases():
         c = attr.cost(phase)
@@ -127,7 +131,7 @@ def test_attribute_real_steps_single_device():
     assert attr.cost("prefill").tokens_per_step == 8
     assert attr.cost("decode").tokens_per_step == 4
     # idempotent: re-attribution returns the cached costs
-    again = eng.attribute_steps()
+    again = eng.attribute_steps(hw=CPU_PEAKS)
     assert again is attr
     assert again.cost("decode") is attr.cost("decode")
     # gauges registered and set
@@ -140,7 +144,7 @@ def test_attribute_real_steps_single_device():
 @pytest.mark.slow
 def test_attribute_real_steps_spec_engine():
     eng = _engine(gamma=2)
-    attr = eng.attribute_steps()
+    attr = eng.attribute_steps(hw=CPU_PEAKS)
     assert set(attr.phases()) == {"prefill", "decode", "draft", "verify"}
     draft, verify = attr.cost("draft"), attr.cost("verify")
     # the timed draft phase wraps gamma jitted calls
@@ -156,7 +160,7 @@ def test_attribute_real_steps_spec_engine():
 def test_attribute_real_steps_mesh(mesh):
     m = mesh(data=2, model=2)
     eng = _engine(mesh=m)
-    attr = eng.attribute_steps()
+    attr = eng.attribute_steps(hw=CPU_PEAKS)
     assert set(attr.phases()) == {"prefill", "decode"}
     for phase in attr.phases():
         c = attr.cost(phase)
@@ -171,7 +175,7 @@ def test_attribute_real_steps_mesh(mesh):
 @pytest.mark.slow
 def test_runtime_join_after_real_run():
     eng = _engine(clock=FakeClock(dt=0.001))
-    eng.attribute_steps()
+    eng.attribute_steps(hw=CPU_PEAKS)
     for i in range(3):
         eng.submit([1, 2, 3, 4 + i], SamplingParams(max_new_tokens=3))
     eng.run()
@@ -194,7 +198,7 @@ def test_runtime_join_after_real_run():
 
 def _seamed_attr():
     obs = Observability(clock=FakeClock())
-    attr = StepAttribution(obs)
+    attr = StepAttribution(obs, hw=CPU_PEAKS)
     attr.register_cost(
         StepCost(phase="decode", flops=1e9, hbm_bytes=2e9,
                  coll_bytes={"total": 0.0}, tokens_per_step=8),
@@ -370,3 +374,14 @@ def test_engine_slo_fires_on_tight_target():
     assert eng.slo.violations()["tight"] >= 1
     names = [e["name"] for e in eng.obs.tracer._events]
     assert "slo_violation" in names
+
+
+def test_device_peaks_are_keyed_by_device_kind():
+    """Roofline peaks come from a table keyed by device_kind; a device
+    without an entry raises instead of borrowing another chip's peaks."""
+    from repro.core.costmodel import hardware_for
+    hw = hardware_for("TPU v5 lite")
+    assert (hw.peak_flops, hw.peak_int8_ops, hw.hbm_bw) == (197e12, 393e12,
+                                                            819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        hardware_for(jax.devices()[0].device_kind)

@@ -2,6 +2,7 @@
 packed KV4, dry-run lowering — the multi-device paths run in a subprocess
 with forced host devices (the main test process keeps 1 device)."""
 import json
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -13,6 +14,8 @@ import pytest
 
 from repro.launch.hlo_analysis import analyze, shape_bytes, shape_dims
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
+
 
 def _run_subprocess(code: str, devices: int = 8) -> str:
     env = {"XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
@@ -20,7 +23,7 @@ def _run_subprocess(code: str, devices: int = 8) -> str:
     import os
     env["PATH"] = os.environ.get("PATH", env["PATH"])
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, cwd="/root/repo", timeout=540)
+                         text=True, env=env, cwd=REPO_ROOT, timeout=540)
     assert out.returncode == 0, out.stderr[-2000:]
     return out.stdout
 
@@ -63,7 +66,8 @@ def test_dryrun_lower_cell_smoke_multidevice():
     out = _run_subprocess(textwrap.dedent("""
         import jax, json
         from repro.launch.dryrun import lower_cell
-        mesh = jax.make_mesh((4, 16), ("data", "model"))
+        from repro.launch.mesh import make_smoke_mesh
+        mesh = make_smoke_mesh(data=4, model=16)
         import repro.models.registry as R
         R.ARCHS = dict(R.ARCHS)
         R.ARCHS['yi-6b'] = R.ARCHS['yi-6b'].replace(n_layers=2)

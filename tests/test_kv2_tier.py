@@ -11,6 +11,7 @@ except ModuleNotFoundError:      # degrade to seeded fixed examples
     from _hypothesis_fallback import given, settings, st
 
 from repro.configs.base import ModelConfig
+from repro.core.costmodel import HardwareConfig
 from repro.core.qlinear import quantize_model_params
 from repro.models.schema import init_params
 from repro.models.schema_builder import build_schema
@@ -18,6 +19,9 @@ from repro.serving import (Engine, PagedKVPool, PoolConfig, SamplingParams,
                            SchedulerConfig)
 from repro.serving import tiering
 from repro.serving.kv_pool import KV2_LOW, KV2_HIGH
+
+# a CPU has no entry in costmodel.DEVICE_PEAKS: pass roofline peaks
+CPU_PEAKS = HardwareConfig()
 
 CFG = ModelConfig(name="tiny-kv2", family="transformer", n_layers=2,
                   d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
@@ -318,5 +322,5 @@ def test_attribute_steps_covers_tiered_decode(qparams):
     eng, _, _ = _run_engine(
         qparams, PoolConfig(n_pages=32, page_size=4, kv2_pages=8,
                             demote_after_steps=10**9), gen=2)
-    attr = eng.attribute_steps()
+    attr = eng.attribute_steps(hw=CPU_PEAKS)
     assert "decode" in attr.phases()

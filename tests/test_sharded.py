@@ -18,7 +18,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core.quantize import quantize_weights
-from repro.distributed.tp import shard_map_compat
 from repro.kernels.ops import sparqle_linear, sparqle_linear_sharded
 from repro.kernels.sparqle_encode import sparqle_encode
 from repro.kernels.sparqle_matmul import sparqle_matmul
@@ -86,7 +85,8 @@ def test_row_sharded_is_single_psum_of_merged_acc(mesh):
     def body(qv, wv):
         return jax.lax.psum(full_acc(qv, wv), "model")
 
-    fn = shard_map_compat(body, m, in_specs=(P(None, "model"),
+    fn = jax.shard_map(
+        body, mesh=m, check_vma=False, in_specs=(P(None, "model"),
                                              P("model", None)),
                           out_specs=P(None, None))
     np.testing.assert_array_equal(np.asarray(fn(q, wq)), np.asarray(ref))
@@ -125,8 +125,8 @@ def test_sparqle_encode_sharded_rows_bit_exact(mesh, ways):
     def body(xv, sv):
         return sparqle_encode(xv, sv, bm=4, bk=32)
 
-    fn = shard_map_compat(
-        body, m,
+    fn = jax.shard_map(
+        body, mesh=m, check_vma=False,
         in_specs=(P("model", None), P("model", None)),
         out_specs=(P("model", None), P("model", None), P("model", None),
                    P("model", None)))
@@ -156,8 +156,8 @@ def test_paged_decode_attention_kv_head_sharded(mesh, ways):
 
     ref = kv4_paged_decode_attention(q, kq, ks, vq, vs, bt, pos)
 
-    fn = shard_map_compat(
-        kv4_paged_decode_attention, m,
+    fn = jax.shard_map(
+        kv4_paged_decode_attention, mesh=m, check_vma=False,
         in_specs=(P(None, "model"), P(None, None, "model"),
                   P(None, None, "model"), P(None, None, "model"),
                   P(None, None, "model"), P(None, None), P(None)),
@@ -186,8 +186,8 @@ def test_paged_verify_attention_kv_head_sharded(mesh):
 
     ref = kv4_paged_verify_attention(q, kq, ks, vq, vs, bt, pos)
 
-    fn = shard_map_compat(
-        kv4_paged_verify_attention, m,
+    fn = jax.shard_map(
+        kv4_paged_verify_attention, mesh=m, check_vma=False,
         in_specs=(P(None, None, "model"), P(None, None, "model"),
                   P(None, None, "model"), P(None, None, "model"),
                   P(None, None, "model"), P(None, None), P(None)),

@@ -12,9 +12,9 @@ Three checks over every markdown file (README.md + docs/*.md):
    (``serve.py``) must exist *somewhere* in the tree. Math-looking
    spans (``hd/2``, shapes, calls with parens) are ignored.
 2. **CLI flags.** Every ``--flag`` mentioned in inline code or fenced
-   shell/python blocks must be a real argparse option somewhere under
-   ``src/``, ``benchmarks/`` or ``tools/`` (external tool flags like
-   ``--xla_*`` are allowlisted).
+   shell/python blocks must be a real argparse option of a top-level
+   script or of one under ``src/``, ``benchmarks/`` or ``tools/``
+   (external tool flags like ``--xla_*`` are allowlisted).
 3. **Cross-references.** Every ``[[name]]`` wiki-style link must
    resolve to ``docs/name.md``.
 
@@ -54,9 +54,11 @@ def markdown_files(root: pathlib.Path) -> list:
 
 def argparse_flags(root: pathlib.Path) -> set:
     flags = set()
+    scripts = list(root.glob("*.py"))          # top-level entry scripts
     for top in ("src", "benchmarks", "tools"):
-        for py in (root / top).rglob("*.py"):
-            flags.update(ADD_ARGUMENT.findall(py.read_text()))
+        scripts += (root / top).rglob("*.py")
+    for py in scripts:
+        flags.update(ADD_ARGUMENT.findall(py.read_text()))
     return flags
 
 
