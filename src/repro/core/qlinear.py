@@ -148,31 +148,40 @@ def _dual_pass_matmul(q: jax.Array, wq: jax.Array, batched: bool,
     ``msb_skip`` drops the sparse pass from the traced program: the result
     is the dense LSB4 contribution alone (equal to dequantizing the LSB
     plane by itself), the draft forward of self-speculative decoding.
+
+    The encode and the two passes run under the named scopes
+    ``act_encode``, ``lsb_dot`` and ``msb_dot``, which name their ops in
+    the compiled program's metadata (and so in a device trace).
     """
-    if wire_format == "packed":
-        pa = encode_packed(q.reshape(-1, q.shape[-1]))
-        planes = unpack_planes(pa)
-        lsb = planes.lsb4.reshape(q.shape)
-        msb = planes.msb4.reshape(q.shape)
-    else:
-        act = encode(q)
-        lsb, msb = act.lsb4, act.msb4
+    with jax.named_scope("act_encode"):
+        if wire_format == "packed":
+            pa = encode_packed(q.reshape(-1, q.shape[-1]))
+            planes = unpack_planes(pa)
+            lsb = planes.lsb4.reshape(q.shape)
+            msb = planes.msb4.reshape(q.shape)
+        else:
+            act = encode(q)
+            lsb, msb = act.lsb4, act.msb4
     if batched:   # (E, C, K) x (E, K, N)
         dims = (((2,), (1,)), ((0,), (0,)))
     else:         # (M, K) x (K, N)
         dims = (((1,), (0,)), ((), ()))
-    dense = jax.lax.dot_general(lsb, wq, dims,
-                                preferred_element_type=jnp.int32)
+    with jax.named_scope("lsb_dot"):
+        dense = jax.lax.dot_general(lsb, wq, dims,
+                                    preferred_element_type=jnp.int32)
     if msb_skip:
         return dense
-    sparse = jax.lax.dot_general(msb, wq, dims,
-                                 preferred_element_type=jnp.int32)
+    with jax.named_scope("msb_dot"):
+        sparse = jax.lax.dot_general(msb, wq, dims,
+                                     preferred_element_type=jnp.int32)
     return dense + sparse * 16
 
 
 def _single_pass_matmul(q: jax.Array, wq: jax.Array, batched: bool) -> jax.Array:
     dims = (((2,), (1,)), ((0,), (0,))) if batched else (((1,), (0,)), ((), ()))
-    return jax.lax.dot_general(q, wq, dims, preferred_element_type=jnp.int32)
+    with jax.named_scope("int8_dot"):
+        return jax.lax.dot_general(q, wq, dims,
+                                   preferred_element_type=jnp.int32)
 
 
 def linear(x: jax.Array, w, b: Optional[jax.Array] = None, *,
@@ -243,7 +252,8 @@ def _quantized_apply(x: jax.Array, sl: SparqleLinear,
     if sl.col_mask is not None and sl.l is not None:
         mask = sl.col_mask[:, None, :] if batched else sl.col_mask
         q = apply_clipping(q, mask, sl.l, sl.h)
-    wq = sl.unpacked_q()
+    with jax.named_scope("weight_unpack"):
+        wq = sl.unpacked_q()
     if sl.mode == "sparqle":
         acc = _dual_pass_matmul(q, wq, batched, sl.wire_format,
                                 msb_skip=_MSB_SKIP)

@@ -19,7 +19,7 @@ import jax
 from jax.experimental import pallas as pl
 
 
-def pallas_call(kernel, **kwargs):
+def pallas_call(kernel, *, name: str, **kwargs):
     """``pl.pallas_call`` whose execution mode follows the lowering platform.
 
     On TPU the kernel is compiled by Mosaic; on CPU (the test platform) it
@@ -27,9 +27,13 @@ def pallas_call(kernel, **kwargs):
     — there is no silent interpreter fallback on an accelerator. The choice
     is made per lowering (``jax.lax.platform_dependent``), so a program
     compiled for a described TPU from a CPU host gets the real kernel.
+
+    ``name`` names the kernel's custom call in the compiled program, and so
+    its events in a device trace; without it every kernel would take the
+    name of its ``platform_dependent`` branch.
     """
-    compiled = pl.pallas_call(kernel, **kwargs)
-    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+    compiled = pl.pallas_call(kernel, name=name, **kwargs)
+    interpreted = pl.pallas_call(kernel, interpret=True, name=name, **kwargs)
 
     def call(*args):
         return jax.lax.platform_dependent(*args, cpu=interpreted,

@@ -168,6 +168,7 @@ def kv4_decode_attention(
     )
     return pallas_call(
         functools.partial(_kernel, n_s=n_s, bs=bs, scale=hd ** -0.5),
+        name="kv4_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -233,6 +234,7 @@ def kv4_paged_decode_attention(
     )
     return pallas_call(
         functools.partial(_paged_kernel, n_s=n_s, bs=ps, scale=hd ** -0.5),
+        name="kv4_paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -301,6 +303,7 @@ def kv4_paged_verify_attention(
     return pallas_call(
         functools.partial(_paged_verify_kernel, n_s=n_s, bs=ps,
                           scale=hd ** -0.5),
+        name="kv4_paged_verify_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, kvh, g, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -390,6 +393,7 @@ def kv_tiered_paged_decode_attention(
     return pallas_call(
         functools.partial(_tiered_paged_kernel, n_s=n_s, bs=ps,
                           scale=hd ** -0.5),
+        name="kv_tiered_paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

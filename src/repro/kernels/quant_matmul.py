@@ -58,6 +58,7 @@ def quant_matmul(
     n_k = k // bk
     return pallas_call(
         functools.partial(_kernel, n_k=n_k),
+        name="quant_matmul",
         grid=(m // bm, n // bn, n_k),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

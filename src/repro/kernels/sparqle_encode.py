@@ -80,6 +80,7 @@ def sparqle_encode(
     grid = (m // bm, k // bk)
     return pallas_call(
         _kernel,
+        name="sparqle_encode",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
@@ -123,6 +124,7 @@ def sparqle_encode_packed(
     nw = bk // PBM_WORD_BITS
     return pallas_call(
         _kernel_packed,
+        name="sparqle_encode_packed",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j: (i, j)),

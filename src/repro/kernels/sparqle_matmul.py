@@ -186,6 +186,7 @@ def _call(kernel, grid, act_specs, act_args, w, act_scale, w_scale,
         args = (tile_pop, *act_args, w, act_scale, w_scale)
     return pallas_call(
         functools.partial(kernel, n_k=n_k, acc_out=acc_out),
+        name="sparqle_matmul_lsb" if msb_skip else "sparqle_matmul",
         grid=grid,
         in_specs=[
             *in_specs,

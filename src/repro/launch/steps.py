@@ -17,7 +17,6 @@ scale-critical lives here:
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -238,24 +237,6 @@ def _mesh_layout(cfg: ModelConfig, mesh: Mesh):
     mways = mesh_axis_size(mesh, "model")
     daxis = "data" if mesh_axis_size(mesh, "data") > 1 else None
     return shard_model_config(cfg, mways), mways, daxis
-
-
-def with_trace_annotation(name: str, fn):
-    """Wrap an already-compiled step so each CALL runs inside
-    ``jax.profiler.TraceAnnotation(name)`` — the annotation brackets the
-    host-side dispatch, it is never traced into the computation, so the
-    wrapped fn's jaxpr/HLO and donation behavior are untouched. No-op
-    passthrough if the profiler API is unavailable."""
-    try:
-        annotation = jax.profiler.TraceAnnotation
-    except AttributeError:
-        return fn
-
-    @functools.wraps(fn)
-    def wrapped(*args, **kwargs):
-        with annotation(name):
-            return fn(*args, **kwargs)
-    return wrapped
 
 
 def abstract_like(tree: Any) -> Any:

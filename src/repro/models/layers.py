@@ -227,13 +227,14 @@ def act_wire_telemetry(x: jax.Array) -> dict:
     from repro.core.quantize import quantize_activations
     from repro.core.sparqle import subprecision_sparsity
 
-    q = quantize_activations(x, bits=8, per_token=True).q
-    return {
-        "sparsity": subprecision_sparsity(q, axis=-1),
-        "wire_bytes": measured_wire_bytes_rows(q).astype(jnp.float32),
-        "dense_bytes": jnp.full(q.shape[:-1], dense_bytes_rows(q),
-                                jnp.float32),
-    }
+    with jax.named_scope("telemetry"):
+        q = quantize_activations(x, bits=8, per_token=True).q
+        return {
+            "sparsity": subprecision_sparsity(q, axis=-1),
+            "wire_bytes": measured_wire_bytes_rows(q).astype(jnp.float32),
+            "dense_bytes": jnp.full(q.shape[:-1], dense_bytes_rows(q),
+                                    jnp.float32),
+        }
 
 
 def stack_sublayer_telemetry(tels: list) -> dict:

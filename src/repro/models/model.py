@@ -553,20 +553,22 @@ def embed_inputs(cfg: ModelConfig, params: Params,
 
 
 def head_logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
-    x = _norm(cfg, params["final_norm"], x)
-    if cfg.tie_embeddings:
-        # tied head: the embedding table stays replicated under TP (token
-        # lookup needs the full vocab), so logits are already complete
-        return linear(x, params["embed"]["table"].T)
-    logits = linear(x, params["lm_head"])
-    ctx = tp_ctx()
-    if ctx is not None and logits.shape[-1] != cfg.vocab:
-        # column-parallel head: gather the vocab shards back into the
-        # full distribution (exact concatenation, shard order = axis
-        # order) — sampling policy lives host-side in the engine
-        logits = jax.lax.all_gather(logits, ctx.axis, axis=logits.ndim - 1,
-                                    tiled=True)
-    return logits
+    with jax.named_scope("lm_head"):
+        x = _norm(cfg, params["final_norm"], x)
+        if cfg.tie_embeddings:
+            # tied head: the embedding table stays replicated under TP
+            # (token lookup needs the full vocab), so logits are already
+            # complete
+            return linear(x, params["embed"]["table"].T)
+        logits = linear(x, params["lm_head"])
+        ctx = tp_ctx()
+        if ctx is not None and logits.shape[-1] != cfg.vocab:
+            # column-parallel head: gather the vocab shards back into the
+            # full distribution (exact concatenation, shard order = axis
+            # order) — sampling policy lives host-side in the engine
+            logits = jax.lax.all_gather(logits, ctx.axis,
+                                        axis=logits.ndim - 1, tiled=True)
+        return logits
 
 
 # ---------------------------------------------------------------------------
@@ -704,22 +706,24 @@ def attn_decode_paged(cfg: ModelConfig, ld: LayerDef, p: Params,
             tier_tables[bidx, jnp.clip(pos // ps, 0, n_steps - 1)] == 0,
             page, 0)
     off = pos % ps
-    pool = {
-        **pool,                       # KV2 slab (if any) passes through
-        "k_q": pool["k_q"].at[page, off].set(kq),
-        "k_s": pool["k_s"].at[page, off].set(ks),
-        "v_q": pool["v_q"].at[page, off].set(vq),
-        "v_s": pool["v_s"].at[page, off].set(vs),
-    }
-    if tier_tables is None:
-        o = kv4_paged_decode_attention(
-            q.reshape(b, kvh, g, cfg.hd), pool["k_q"], pool["k_s"],
-            pool["v_q"], pool["v_s"], block_tables, pos)
-    else:
-        o = kv_tiered_paged_decode_attention(
-            q.reshape(b, kvh, g, cfg.hd), pool["k_q"], pool["k_s"],
-            pool["v_q"], pool["v_s"], pool["k2_q"], pool["k2_s"],
-            pool["v2_q"], pool["v2_s"], block_tables, tier_tables, pos)
+    with jax.named_scope("kv_write"):
+        pool = {
+            **pool,                   # KV2 slab (if any) passes through
+            "k_q": pool["k_q"].at[page, off].set(kq),
+            "k_s": pool["k_s"].at[page, off].set(ks),
+            "v_q": pool["v_q"].at[page, off].set(vq),
+            "v_s": pool["v_s"].at[page, off].set(vs),
+        }
+    with jax.named_scope("paged_attention"):
+        if tier_tables is None:
+            o = kv4_paged_decode_attention(
+                q.reshape(b, kvh, g, cfg.hd), pool["k_q"], pool["k_s"],
+                pool["v_q"], pool["v_s"], block_tables, pos)
+        else:
+            o = kv_tiered_paged_decode_attention(
+                q.reshape(b, kvh, g, cfg.hd), pool["k_q"], pool["k_s"],
+                pool["v_q"], pool["v_s"], pool["k2_q"], pool["k2_s"],
+                pool["v2_q"], pool["v2_s"], block_tables, tier_tables, pos)
     o = o.reshape(b, cfg.n_heads * cfg.hd)
     return linear(o, p["wo"], p.get("bo"), tp="row"), pool
 

@@ -21,7 +21,9 @@ page pool and speculative decoder (see docs/observability.md).
 """
 from __future__ import annotations
 
+import contextlib
 import time
+from typing import Optional
 
 from repro.obs.metrics import (DEFAULT_LATENCY_BUCKETS, METRIC_NAME_RE,
                                Counter, Gauge, Histogram, MetricsRegistry)
@@ -45,6 +47,20 @@ class Observability:
         self.tracer = Tracer(clock=clock, capacity=trace_capacity,
                              enabled=trace,
                              xla_annotations=xla_annotations)
+        self.step_seconds = self.registry.histogram(
+            "serving_step_seconds", "host-side latency of one engine-step "
+            "phase (includes device sync)", unit="seconds",
+            labelnames=("phase",))
+
+    @contextlib.contextmanager
+    def phase(self, phase: str, span: Optional[str] = None, **args):
+        """Time one engine-step phase once, two ways: as the tracer span
+        ``span`` (default: the phase's name) with ``args``, and as one
+        observation of ``serving_step_seconds{phase=}``, which is made
+        with tracing off too. Yields the span (see ``Tracer.span``)."""
+        with self.tracer.span(span or phase, **args) as s, \
+                self.step_seconds.time(phase=phase):
+            yield s
 
 
 # attribution/slo import AFTER Observability: they are host-only leaf

@@ -15,14 +15,16 @@ begin/ends are recorded host-side only — never inside traced/jitted
 code — so tracing changes no compiled program.
 
 ``xla_annotations=True`` additionally wraps each span body in
-``jax.profiler.TraceAnnotation`` (when available), so engine spans line
-up with XLA device rows when a jax profiler session is active on a real
-backend. Import/runtime failures degrade to plain spans — the tracer
-itself never requires jax.
+``jax.profiler.TraceAnnotation`` (when available), with the span's
+scalar args as the annotation's metadata, so engine spans and their args
+land in the profiler's own trace, on its clock, beside the XLA device
+rows. Import/runtime failures degrade to plain spans — the tracer itself
+never requires jax.
 
     tr = Tracer()
-    with tr.span("decode_step", step=i):
+    with tr.span("decode_step", step=i) as s:
         ...
+        s.set(tokens=n)                  # args known only at the end
     tr.export_chrome("trace.json")       # open in ui.perfetto.dev
 """
 from __future__ import annotations
@@ -32,20 +34,46 @@ import contextlib
 import dataclasses
 import json
 import time
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 ENGINE_TRACK = 0            # tid 0: engine-step phases
 REQUEST_TRACK_BASE = 1      # tid rid + 1: per-request lifecycle spans
 
 
+_SCALARS = (bool, int, float, str)
+
+
 @dataclasses.dataclass
 class SpanHandle:
-    """An open span (returned by :meth:`Tracer.begin`)."""
+    """An open span (returned by :meth:`Tracer.begin`, yielded by
+    :meth:`Tracer.span`)."""
     name: str
     track: int
     t0_us: float
     args: Dict
     closed: bool = False
+    annotation: Any = None      # the profiler annotation, when passed on
+
+    def set(self, **args) -> None:
+        """Add args to the open span (and to its profiler annotation)."""
+        self.args.update(args)
+        if self.annotation is not None:
+            self.annotation.set_metadata(**_scalar_args(args))
+
+
+class _NoSpan:
+    """What a disabled tracer's :meth:`Tracer.span` yields."""
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def _scalar_args(args: Dict) -> Dict:
+    """The args a profiler annotation can carry as stats."""
+    return {k: v for k, v in args.items() if isinstance(v, _SCALARS)}
 
 
 class Tracer:
@@ -115,21 +143,28 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, track: int = ENGINE_TRACK, **args):
-        """Record the with-block as one complete ("X") trace event."""
+        """Record the with-block as one complete ("X") trace event.
+
+        Yields the open :class:`SpanHandle` (a no-op stand-in when
+        disabled), whose ``set(**args)`` adds args known only inside the
+        block. With ``xla_annotations`` the block also runs inside
+        ``jax.profiler.TraceAnnotation(name, **scalar args)``."""
         if not self.enabled:
-            yield
+            yield _NO_SPAN
             return
         ann = None
         if self.xla_annotations:
             try:
                 import jax
-                ann = jax.profiler.TraceAnnotation(name)
+                ann = jax.profiler.TraceAnnotation(name,
+                                                   **_scalar_args(args))
                 ann.__enter__()
             except Exception:
                 ann = None
         h = self.begin(name, track=track, **args)
+        h.annotation = ann
         try:
-            yield
+            yield h
         finally:
             if ann is not None:
                 ann.__exit__(None, None, None)

@@ -160,10 +160,6 @@ class Engine:
         self._m_tpot = r.histogram(
             "serving_tpot_seconds", "gap between consecutive emitted "
             "tokens of one request", unit="seconds")
-        self._m_step_lat = r.histogram(
-            "serving_step_seconds", "host-side latency of one engine-step "
-            "phase (includes device sync)", unit="seconds",
-            labelnames=("phase",))
         self._m_wire = r.counter(
             "serving_wire_bytes_total", "measured packed-wire activation "
             "bytes (inter-layer hidden stream)", unit="bytes")
@@ -224,36 +220,36 @@ class Engine:
     def step(self) -> List[Tuple[int, int]]:
         """One scheduler iteration. Returns [(rid, token), ...] emitted.
 
-        Each phase (schedule / per-chunk prefill / decode batch) is timed
-        into ``serving_step_seconds{phase=}`` and spanned on the tracer's
-        engine track — all host-side, around (never inside) the jitted
-        calls.
+        Each phase (schedule / per-chunk prefill / decode batch / demote)
+        is spanned on the tracer's engine track and timed into
+        ``serving_step_seconds{phase=}`` by one ``Observability.phase``
+        — all host-side, around (never inside) the jitted calls.
         """
-        tr = self.obs.tracer
+        obs = self.obs
         events: List[Tuple[int, int]] = []
-        with tr.span("engine_step", step=self.steps):
+        with obs.tracer.span("engine_step", step=self.steps):
             if self._kv2:
                 self.pool.tick()
-            with self._m_step_lat.time(phase="schedule"):
+            with obs.phase("schedule",
+                           waiting=len(self.sched.waiting)) as span:
                 plan = self.sched.schedule()
+                span.set(decode=len(plan.decode))
             if self.slo is not None:
                 self.slo.observe("queue_depth", float(len(self.sched.waiting)))
             for req, start, n in plan.prefill:
-                with tr.span("prefill_chunk", rid=req.rid, start=start,
-                             n=n):
-                    with self._m_step_lat.time(phase="prefill"):
-                        events.extend(
-                            self._run_prefill_chunk(req, start, n))
+                with obs.phase("prefill", "prefill_chunk", rid=req.rid,
+                               start=start, n=n):
+                    events.extend(self._run_prefill_chunk(req, start, n))
                 self._m_tokens.inc(n, phase="prefill")
             if plan.decode:
-                with tr.span("decode_batch", slots=len(plan.decode)):
-                    with self._m_step_lat.time(phase="decode"):
-                        events.extend(self._run_decode(plan.decode))
+                with obs.phase("decode", "decode_batch",
+                               slots=len(plan.decode)):
+                    events.extend(self._run_decode(plan.decode))
             if self._kv2:
                 # background cold sweep AFTER the decode writes landed:
                 # a page demoted here is first read (tier-routed) next
                 # step, so the step that demotes never races its reader
-                with self._m_step_lat.time(phase="demote"):
+                with obs.phase("demote"):
                     self.pool.demote_cold()
         self._m_steps.inc()
         self.steps += 1
@@ -414,11 +410,12 @@ class Engine:
         if self.layer_sparsity_sum is not None and self.wire_tokens:
             mean_sparsity = float(
                 self.layer_sparsity_sum.mean() / self.wire_tokens)
+        step_seconds = self.obs.step_seconds
         for phase in self._attr.phases():
-            n = self._m_step_lat.count(phase=phase)
+            n = step_seconds.count(phase=phase)
             if n:
                 self._attr.observe_runtime(
-                    phase, self._m_step_lat.mean(phase=phase),
+                    phase, step_seconds.mean(phase=phase),
                     sparsity=mean_sparsity)
         if self.layer_wire_bytes is not None and self.wire_tokens:
             from repro.core.packing import PBM_WORD_BITS, pad_k
@@ -544,7 +541,10 @@ class Engine:
         ev = self._emit(req, self._sample(req, np.asarray(logits[0])))
         return [ev] if ev else []
 
-    def _run_decode(self, decode: List[Request]) -> List[Tuple[int, int]]:
+    def _decode_inputs(self, decode: List[Request]) -> Tuple:
+        """The decode step's device arguments after the pool state: token,
+        position and block-table arrays (and the tier table when the KV2
+        ladder is armed), one row per decode slot."""
         B = self._n_slots
         token = np.zeros((B,), np.int32)
         pos = np.zeros((B,), np.int32)
@@ -565,30 +565,43 @@ class Engine:
             token[req.slot] = req.context[-1]
             pos[req.slot] = len(req.context) - 1
             tables[req.slot] = self._block_table_row(req)
-        if self._kv2:
+        args = (token, pos, tables) + ((tiers,) if self._kv2 else ())
+        return tuple(jnp.asarray(a) for a in args)
+
+    def _run_decode(self, decode: List[Request]) -> List[Tuple[int, int]]:
+        """One decode step, in spans nested under ``decode_batch``:
+        inputs, launch, wait (logits to the host), readback (telemetry to
+        the host), sample, emit. Sampling reads nothing that accounting
+        and emission write, so sampling every slot first emits the same
+        tokens in the same order."""
+        tr = self.obs.tracer
+        with tr.span("decode_inputs"):
+            args = self._decode_inputs(decode)
+        with tr.span("decode_launch"):
             logits, self.pool.state, tel = self._decode_fn(
-                self.params, self.pool.state, jnp.asarray(token),
-                jnp.asarray(pos), jnp.asarray(tables), jnp.asarray(tiers))
-        else:
-            logits, self.pool.state, tel = self._decode_fn(
-                self.params, self.pool.state, jnp.asarray(token),
-                jnp.asarray(pos), jnp.asarray(tables))
-        logits = np.asarray(logits)
-        sparsity = np.asarray(tel["sparsity"])
-        layer_wire = np.asarray(tel["layer_wire_bytes"], np.float64)
-        layer_dense = np.asarray(tel["layer_dense_bytes"], np.float64)
-        layer_spars = np.asarray(tel["layer_sparsity"], np.float64)
+                self.params, self.pool.state, *args)
+        with tr.span("decode_wait", bytes=logits.nbytes):
+            logits = np.asarray(logits)
+        tel_keys = ("sparsity", "layer_wire_bytes", "layer_dense_bytes",
+                    "layer_sparsity")
+        with tr.span("decode_readback",
+                     bytes=sum(tel[k].nbytes for k in tel_keys)):
+            sparsity, layer_wire, layer_dense, layer_spars = (
+                np.asarray(tel[k], np.float64) for k in tel_keys)
+        with tr.span("decode_sample"):
+            tokens = [self._sample(req, logits[req.slot]) for req in decode]
         events = []
-        for req in decode:
-            req.sparsity_sum += float(sparsity[req.slot])
-            req.sparsity_n += 1
-            self._account_wire(
-                req, float(layer_wire[:, req.slot].sum()),
-                float(layer_dense[:, req.slot].sum()),
-                layer_wire[:, req.slot], layer_dense[:, req.slot],
-                layer_spars[:, req.slot], 1)
-            ev = self._emit(req, self._sample(req, logits[req.slot]))
-            if ev:
-                events.append(ev)
+        with tr.span("decode_emit"):
+            for req, token in zip(decode, tokens):
+                req.sparsity_sum += float(sparsity[req.slot])
+                req.sparsity_n += 1
+                self._account_wire(
+                    req, float(layer_wire[:, req.slot].sum()),
+                    float(layer_dense[:, req.slot].sum()),
+                    layer_wire[:, req.slot], layer_dense[:, req.slot],
+                    layer_spars[:, req.slot], 1)
+                ev = self._emit(req, token)
+                if ev:
+                    events.append(ev)
         self._m_tokens.inc(len(decode), phase="decode")
         return events

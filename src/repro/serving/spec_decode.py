@@ -223,31 +223,29 @@ class SpeculativeEngine(Engine):
         jtables = jnp.asarray(tables)
         cur = jnp.asarray(token)
         dlogs = []
-        with self.obs.tracer.span("spec_draft", slots=len(decode),
-                                  gamma=g):
-            with self._m_step_lat.time(phase="draft"):
-                for i in range(g):
-                    dlg, self.pool.state, _ = self._draft_fn(
-                        self.params, self.pool.state, cur,
-                        jpos + jnp.int32(i), jtables)
-                    dlg = np.asarray(dlg)
-                    dlogs.append(dlg)
-                    nxt = np.zeros((B,), np.int32)
-                    for req in decode:
-                        nxt[req.slot] = self._sample(req, dlg[req.slot])
-                    window[:, i + 1] = nxt
-                    cur = jnp.asarray(nxt)
+        with self.obs.phase("draft", "spec_draft", slots=len(decode),
+                            gamma=g):
+            for i in range(g):
+                dlg, self.pool.state, _ = self._draft_fn(
+                    self.params, self.pool.state, cur,
+                    jpos + jnp.int32(i), jtables)
+                dlg = np.asarray(dlg)
+                dlogs.append(dlg)
+                nxt = np.zeros((B,), np.int32)
+                for req in decode:
+                    nxt[req.slot] = self._sample(req, dlg[req.slot])
+                window[:, i + 1] = nxt
+                cur = jnp.asarray(nxt)
         draft_logits = np.stack(dlogs, axis=1)          # (B, γ, V)
         self._m_tokens.inc(len(decode) * g, phase="draft")
 
         # ---- verify: one full-precision batched window step ----
-        with self.obs.tracer.span("spec_verify", slots=len(decode),
-                                  window=g + 1):
-            with self._m_step_lat.time(phase="verify"):
-                vlg, self.pool.state, tel = self._verify_fn(
-                    self.params, self.pool.state, jnp.asarray(window),
-                    jpos, jtables)
-                vlg = np.asarray(vlg)                   # (B, γ+1, V)
+        with self.obs.phase("verify", "spec_verify", slots=len(decode),
+                            window=g + 1):
+            vlg, self.pool.state, tel = self._verify_fn(
+                self.params, self.pool.state, jnp.asarray(window),
+                jpos, jtables)
+            vlg = np.asarray(vlg)                       # (B, γ+1, V)
         self._m_tokens.inc(len(decode) * (g + 1), phase="verify")
         sparsity = np.asarray(tel["sparsity"])
         layer_wire = np.asarray(tel["layer_wire_bytes"], np.float64)

@@ -241,9 +241,48 @@ def test_tracer_open_spans_flushed_and_ring_bound():
 def test_tracer_disabled_is_noop():
     tr = Tracer(enabled=False)
     assert tr.begin("x") is None
-    with tr.span("y"):
+    with tr.span("y") as s:
         tr.instant("z")
+        s.set(n=1)
     assert len(tr) == 0
+
+
+def test_tracer_span_passes_scalar_args_to_the_profiler(monkeypatch):
+    """With ``xla_annotations`` a span enters a profiler annotation that
+    carries its scalar args, and args set inside the block follow."""
+    made = []
+
+    class Annotation:
+        def __init__(self, name, **kwargs):
+            self.name, self.kwargs, self.entered = name, dict(kwargs), 0
+            made.append(self)
+
+        def __enter__(self):
+            self.entered += 1
+
+        def __exit__(self, *exc):
+            self.entered -= 1
+
+        def set_metadata(self, **kwargs):
+            self.kwargs.update(kwargs)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = Tracer(clock=FakeClock(), xla_annotations=True)
+    with tr.span("decode_batch", slots=16, tag="x", ok=True, frac=0.5,
+                 shape=(2, 3)) as s:
+        assert made[0].entered == 1
+        s.set(bytes=4096, rows=[1])
+    (ann,) = made
+    assert ann.name == "decode_batch" and ann.entered == 0
+    assert ann.kwargs == {"slots": 16, "tag": "x", "ok": True, "frac": 0.5,
+                          "bytes": 4096}
+    (ev,) = [e for e in tr.export()["traceEvents"] if e["ph"] == "X"]
+    assert ev["args"] == {"slots": 16, "tag": "x", "ok": True, "frac": 0.5,
+                          "shape": (2, 3), "bytes": 4096, "rows": [1]}
+    tr.xla_annotations = False
+    with tr.span("schedule"):
+        pass
+    assert len(made) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -395,6 +434,80 @@ def test_engine_metrics_and_trace_end_to_end(qparams):
                for e in trace["traceEvents"])
 
 
+DECODE_CHILDREN = ["decode_inputs", "decode_launch", "decode_wait",
+                   "decode_readback", "decode_sample", "decode_emit"]
+PHASE_SPANS = {"schedule": "schedule", "prefill": "prefill_chunk",
+               "decode": "decode_batch"}
+
+
+def _run_tiny_engine(qparams, obs):
+    clk = FakeClock(dt=0.001)
+    obs = obs(clk)
+    eng = Engine(CFG, qparams, clock=clk, obs=obs)
+    rng = np.random.default_rng(1)
+    for n in (12, 20):
+        eng.submit(list(rng.integers(1, 127, size=n)),
+                   SamplingParams(max_new_tokens=5, temperature=0.0))
+    eng.run()
+    return eng
+
+
+def _inside(child, parent):
+    return (child["ts"] >= parent["ts"] and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"])
+
+
+@pytest.mark.slow
+def test_engine_decode_and_schedule_spans_nest_in_order(qparams):
+    """Each ``decode_batch`` holds the six decode child spans, in order and
+    nowhere else; each ``engine_step`` holds one ``schedule``; and every
+    timed phase has exactly one span per histogram observation."""
+    eng = _run_tiny_engine(qparams, lambda clk: Observability(clock=clk))
+    assert eng.obs.tracer.dropped == 0
+    evs = [e for e in eng.obs.tracer.export()["traceEvents"]
+           if e["ph"] == "X" and e["tid"] == 0]
+    by_name = {}
+    for e in evs:
+        by_name.setdefault(e["name"], []).append(e)
+    batches = by_name["decode_batch"]
+    assert batches and all(e["args"]["slots"] >= 1 for e in batches)
+    for batch in batches:
+        kids = sorted((e for e in evs
+                       if e is not batch and _inside(e, batch)),
+                      key=lambda e: e["ts"])
+        assert [e["name"] for e in kids] == DECODE_CHILDREN
+        assert all(a["ts"] + a["dur"] <= b["ts"]
+                   for a, b in zip(kids, kids[1:]))
+        assert kids[2]["args"]["bytes"] == eng._n_slots * CFG.vocab * 4
+        assert kids[3]["args"]["bytes"] > 0
+    for name in DECODE_CHILDREN:
+        assert len(by_name[name]) == len(batches)
+    steps = by_name["engine_step"]
+    for step in steps:
+        sched = [e for e in by_name["schedule"] if _inside(e, step)]
+        assert len(sched) == 1
+        assert set(sched[0]["args"]) == {"waiting", "decode"}
+    assert sum(e["args"]["decode"] for e in by_name["schedule"]) == sum(
+        e["args"]["slots"] for e in batches)
+    hist = eng.obs.registry.get("serving_step_seconds")
+    for phase, span in PHASE_SPANS.items():
+        assert hist.count(phase=phase) == len(by_name[span]) > 0, phase
+    assert hist.count(phase="schedule") == len(steps) == eng.steps
+
+
+@pytest.mark.slow
+def test_engine_with_tracing_off_records_no_span_but_times_phases(qparams):
+    eng = _run_tiny_engine(
+        qparams, lambda clk: Observability(clock=clk, trace=False))
+    assert len(eng.obs.tracer) == 0
+    assert not [e for e in eng.obs.tracer.export()["traceEvents"]
+                if e["ph"] != "M"]
+    hist = eng.obs.registry.get("serving_step_seconds")
+    assert hist.count(phase="schedule") == eng.steps > 0
+    assert hist.count(phase="prefill") >= 2
+    assert hist.count(phase="decode") > 0
+
+
 @pytest.mark.slow
 def test_spec_engine_draft_token_accounting(qparams):
     eng = SpeculativeEngine(CFG, qparams, spec=SpecConfig(gamma=2))
@@ -426,4 +539,9 @@ def test_spec_engine_draft_token_accounting(qparams):
     assert n_batches > 0
     assert step_lat.count(phase="draft") == n_batches
     assert step_lat.count(phase="verify") == n_batches
+    names = [e["name"] for e in eng.obs.tracer.export()["traceEvents"]
+             if e["ph"] == "X"]
+    for phase, span in (("decode", "decode_batch"), ("draft", "spec_draft"),
+                        ("verify", "spec_verify")):
+        assert names.count(span) == step_lat.count(phase=phase), phase
     assert validate_snapshot(eng.metrics_snapshot()) == []

@@ -7,7 +7,9 @@ large for HBM) — none of which interpret-mode tests can see. Each test
 compiles one main-path program at granite-8b widths (B=8, KVH=8, G=4,
 hd=128, 16-token pages) and checks that the Pallas kernel is really
 there (``tpu_custom_call``), i.e. that the platform-dependent launch in
-``repro.kernels.pallas_call`` picked the compiled kernel for TPU.
+``repro.kernels.pallas_call`` picked the compiled kernel for TPU, and
+that the kernel's instruction carries the kernel's own name, which is
+how a device trace names it.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -54,8 +56,10 @@ def _pool_args(dev, width=HD // 2, n_pages=N_PAGES):
     return vals, scales, vals, scales
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{name}" in text, name
 
 
 def test_paged_decode_attention_compiles(one_chip):
@@ -63,7 +67,8 @@ def test_paged_decode_attention_compiles(one_chip):
     _assert_kernel(kv4_paged_decode_attention.lower(
         _sds(one_chip, (B, KVH, G, HD), jnp.bfloat16), *_pool_args(one_chip),
         _sds(one_chip, (B, N_STEPS), jnp.int32),
-        _sds(one_chip, (B,), jnp.int32)).compile())
+        _sds(one_chip, (B,), jnp.int32)).compile(),
+        "kv4_paged_decode_attention")
 
 
 def test_paged_verify_attention_compiles(one_chip):
@@ -71,7 +76,8 @@ def test_paged_verify_attention_compiles(one_chip):
     _assert_kernel(kv4_paged_verify_attention.lower(
         _sds(one_chip, (B, 3, KVH, G, HD), jnp.bfloat16),
         *_pool_args(one_chip), _sds(one_chip, (B, N_STEPS), jnp.int32),
-        _sds(one_chip, (B,), jnp.int32)).compile())
+        _sds(one_chip, (B,), jnp.int32)).compile(),
+        "kv4_paged_verify_attention")
 
 
 def test_tiered_paged_decode_attention_compiles(one_chip):
@@ -80,7 +86,8 @@ def test_tiered_paged_decode_attention_compiles(one_chip):
     _assert_kernel(kv_tiered_paged_decode_attention.lower(
         _sds(one_chip, (B, KVH, G, HD), jnp.bfloat16), *_pool_args(one_chip),
         *_pool_args(one_chip, width=HD // 4, n_pages=9), tables, tables,
-        _sds(one_chip, (B,), jnp.int32)).compile())
+        _sds(one_chip, (B,), jnp.int32)).compile(),
+        "kv_tiered_paged_decode_attention")
 
 
 def test_contiguous_decode_attention_compiles(one_chip):
@@ -90,7 +97,8 @@ def test_contiguous_decode_attention_compiles(one_chip):
     scales = _sds(one_chip, (B, s, KVH), jnp.float32)
     _assert_kernel(kv4_decode_attention.lower(
         _sds(one_chip, (B, KVH, G, HD), jnp.bfloat16), vals, scales, vals,
-        scales, _sds(one_chip, (B,), jnp.int32), bs=PS).compile())
+        scales, _sds(one_chip, (B,), jnp.int32), bs=PS).compile(),
+        "kv4_decode_attention")
 
 
 def test_engine_decode_step_compiles_at_granite_width(one_chip):
@@ -119,6 +127,6 @@ def test_engine_decode_step_compiles_at_granite_width(one_chip):
         place(params), place(pool), _sds(one_chip, (B,), jnp.int32),
         _sds(one_chip, (B,), jnp.int32),
         _sds(one_chip, (B, N_STEPS), jnp.int32)).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "kv4_paged_decode_attention")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
