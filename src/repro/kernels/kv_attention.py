@@ -21,6 +21,18 @@ never touches another head's values, so its bits do not depend on how
 many heads share the block (KV-head sharding is exact). Positions and
 block tables are scalar-prefetched into SMEM.
 
+The grid spans the whole cache axis (the block table's width), but only
+the first :func:`context_pages` blocks of a sequence hold positions
+``<= pos``. A block past them is wholly masked: its scores are all
+``NEG_INF``, so its softmax weights are 0 and the running max,
+denominator and accumulator come out of it unchanged. The scan therefore
+skips such a block: the shared body does no work for it, and the paged
+index maps name the sequence's last context page in its place, so the
+pipeline sees an unchanged block index and issues no copy. The skip is
+exact — a block holding ``pos`` is never skipped, and block 0 always
+holds position 0 — and whatever a skipped block holds (even NaN) cannot
+reach the output.
+
 Three entry points share the same kernel body (``_scan_step``):
 
   * :func:`kv4_decode_attention`        — contiguous (B, S, KVH, …) cache;
@@ -58,6 +70,22 @@ from repro.kernels import pallas_call
 NEG_INF = -2.0e38
 
 
+def context_pages(pos, page_size: int):
+    """How many cache blocks of ``page_size`` tokens hold the positions
+    ``0..pos``: the blocks the scan attends over. Every block from this
+    one on lies wholly past ``pos`` and is skipped. Works on Python ints,
+    NumPy arrays and traced scalars alike, so the kernels' index maps and
+    the engine's page counts share the one rule."""
+    return pos // page_size + 1
+
+
+def _context_col(isb, pos, page_size: int):
+    """Block-table column a grid step at column ``isb`` reads: ``isb``
+    itself, or the sequence's last context page once ``isb`` is past it
+    (an unchanged block index, so the pipeline copies nothing)."""
+    return jnp.minimum(isb, context_pages(pos, page_size) - 1)
+
+
 def _dequant(q_ref, s_ref, h: int, width: int) -> jax.Array:
     """KV head ``h`` of a packed cache block -> (bs, hd) f32.
 
@@ -82,30 +110,37 @@ def _scan_step(pos, s_idx, q_of, kv_of, out_ref, m_ref, l_ref, acc_ref,
     block's place along the cache axis; ``q_of(h)`` gives head ``h``'s
     (G, hd) query rows and ``kv_of(h)`` its dequantized (bs, hd) k and v.
     Every entry point maps its own grid and layout onto these, so the f32
-    computation (and therefore the bits) is identical across them."""
+    computation (and therefore the bits) is identical across them. The
+    init and the drain run at every sequence's first and last step; the
+    attention between them only for blocks that hold a position <= pos."""
     @pl.when(s_idx == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # causal validity: absolute cache position <= pos
-    j = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    for h in range(acc_ref.shape[0]):
-        k, v = kv_of(h)
-        q = q_of(h).astype(jnp.float32)                   # (G, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(j <= pos, s, NEG_INF)               # (G, bs)
-        m_prev = m_ref[h]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[h] = m_new
+    # a block wholly past pos would leave m, l and acc bit for bit as
+    # they are (all scores NEG_INF: p = 0, corr = 1), so it is skipped
+    @pl.when(s_idx < context_pages(pos, bs))
+    def _attend():
+        # causal validity: absolute cache position <= pos
+        j = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        for h in range(acc_ref.shape[0]):
+            k, v = kv_of(h)
+            q = q_of(h).astype(jnp.float32)               # (G, hd)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(j <= pos, s, NEG_INF)           # (G, bs)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(s_idx == n_s - 1)
     def _drain():
@@ -207,12 +242,13 @@ def kv4_paged_decode_attention(
     """Decode attention over a *paged* packed-KV4 pool.
 
     ``block_tables[b, i]`` names the physical page holding sequence ``b``'s
-    tokens ``[i*ps, (i+1)*ps)``. Entries past the sequence's last page may
-    point anywhere (conventionally the null page 0): the absolute-position
-    causal mask ``i*ps + offset <= pos[b]`` discards them. The table is
-    scalar-prefetched (flattened, so SMEM holds no padded 2-D array) and
-    page ids feed the DMA index maps — the pool is only ever touched one
-    page per grid step, in wire format.
+    tokens ``[i*ps, (i+1)*ps)``. Entries past the sequence's last context
+    page (:func:`context_pages`) may point anywhere, and are never read:
+    those grid steps re-name the last context page, so no copy is issued,
+    and the body skips them. The table is scalar-prefetched (flattened, so
+    SMEM holds no padded 2-D array) and page ids feed the DMA index maps —
+    the pool is read only at a sequence's context pages, one page per
+    grid step, in wire format.
     """
     b, kvh, g, hd = q.shape
     _, ps, _, hdp = k_pages.shape
@@ -225,8 +261,8 @@ def kv4_paged_decode_attention(
         in_specs=[
             pl.BlockSpec((1, kvh, g, hd),
                          lambda ib, isb, bt, p: (ib, 0, 0, 0)),
-            *_page_specs(ps, kvh, hdp,
-                         lambda ib, isb, bt, p: bt[ib * n_s + isb]),
+            *_page_specs(ps, kvh, hdp, lambda ib, isb, bt, p: bt[
+                ib * n_s + _context_col(isb, p[ib], ps)]),
         ],
         out_specs=pl.BlockSpec((1, kvh, g, hd),
                                lambda ib, isb, bt, p: (ib, 0, 0, 0)),
@@ -294,8 +330,8 @@ def kv4_paged_verify_attention(
         grid=(b, t, n_s),
         in_specs=[
             pl.BlockSpec((1, 1, kvh, g, hd), q_map),
-            *_page_specs(ps, kvh, hdp,
-                         lambda ib, it, isb, bt, p: bt[ib * n_s + isb]),
+            *_page_specs(ps, kvh, hdp, lambda ib, it, isb, bt, p: bt[
+                ib * n_s + _context_col(isb, p[ib] + it, ps)]),
         ],
         out_specs=pl.BlockSpec((1, 1, kvh, g, hd), q_map),
         scratch_shapes=_scratch(kvh, g, hd),
@@ -370,11 +406,11 @@ def kv_tiered_paged_decode_attention(
     assert k2_pages.shape[-1] * 4 == hd, (hd, k2_pages.shape)
 
     def kv4_page(ib, isb, bt, tt, p):
-        i = ib * n_s + isb
+        i = ib * n_s + _context_col(isb, p[ib], ps)
         return jnp.where(tt[i] == 1, 0, bt[i])
 
     def kv2_page(ib, isb, bt, tt, p):
-        i = ib * n_s + isb
+        i = ib * n_s + _context_col(isb, p[ib], ps)
         return jnp.where(tt[i] == 1, bt[i], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

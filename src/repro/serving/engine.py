@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.kv_attention import context_pages
 from repro.models.model import check_paged_support
 from repro.obs import Observability
 from repro.serving.kv_pool import PagedKVPool, PoolConfig
@@ -160,6 +161,11 @@ class Engine:
         self._m_tpot = r.histogram(
             "serving_tpot_seconds", "gap between consecutive emitted "
             "tokens of one request", unit="seconds")
+        self._m_attn_pages = r.counter(
+            "serving_paged_attn_pages_total", "grid steps of one paged "
+            "decode-attention call (decode slots x block-table columns) "
+            "that read a context page or skip past the context",
+            unit="pages", labelnames=("kind",))
         self._m_wire = r.counter(
             "serving_wire_bytes_total", "measured packed-wire activation "
             "bytes (inter-layer hidden stream)", unit="bytes")
@@ -243,8 +249,8 @@ class Engine:
                 self._m_tokens.inc(n, phase="prefill")
             if plan.decode:
                 with obs.phase("decode", "decode_batch",
-                               slots=len(plan.decode)):
-                    events.extend(self._run_decode(plan.decode))
+                               slots=len(plan.decode)) as span:
+                    events.extend(self._run_decode(plan.decode, span))
             if self._kv2:
                 # background cold sweep AFTER the decode writes landed:
                 # a page demoted here is first read (tier-routed) next
@@ -542,9 +548,9 @@ class Engine:
         return [ev] if ev else []
 
     def _decode_inputs(self, decode: List[Request]) -> Tuple:
-        """The decode step's device arguments after the pool state: token,
-        position and block-table arrays (and the tier table when the KV2
-        ladder is armed), one row per decode slot."""
+        """The decode step's arguments after the pool state, on the host:
+        token, position and block-table arrays (and the tier table when
+        the KV2 ladder is armed), one row per decode slot."""
         B = self._n_slots
         token = np.zeros((B,), np.int32)
         pos = np.zeros((B,), np.int32)
@@ -565,18 +571,32 @@ class Engine:
             token[req.slot] = req.context[-1]
             pos[req.slot] = len(req.context) - 1
             tables[req.slot] = self._block_table_row(req)
-        args = (token, pos, tables) + ((tiers,) if self._kv2 else ())
-        return tuple(jnp.asarray(a) for a in args)
+        return (token, pos, tables) + ((tiers,) if self._kv2 else ())
 
-    def _run_decode(self, decode: List[Request]) -> List[Tuple[int, int]]:
-        """One decode step, in spans nested under ``decode_batch``:
+    def _count_attn_pages(self, pos: np.ndarray) -> Dict[str, int]:
+        """Grid steps of one paged decode-attention call at positions
+        ``pos`` (every decode slot, active or not) that read a context
+        page, and all of its grid steps; the kernel skips the rest.
+        Counted into ``serving_paged_attn_pages_total{kind}``."""
+        read = int(context_pages(pos, self.pool.page_size).sum())
+        steps = pos.shape[0] * self._n_page_steps
+        self._m_attn_pages.inc(read, kind="read")
+        self._m_attn_pages.inc(steps - read, kind="skipped")
+        return {"attn_pages": read, "attn_page_steps": steps}
+
+    def _run_decode(self, decode: List[Request],
+                    span) -> List[Tuple[int, int]]:
+        """One decode step, in spans nested under ``decode_batch``
+        (``span``, which gains the step's attention page counts):
         inputs, launch, wait (logits to the host), readback (telemetry to
         the host), sample, emit. Sampling reads nothing that accounting
         and emission write, so sampling every slot first emits the same
         tokens in the same order."""
         tr = self.obs.tracer
         with tr.span("decode_inputs"):
-            args = self._decode_inputs(decode)
+            host = self._decode_inputs(decode)
+            args = tuple(jnp.asarray(a) for a in host)
+        span.set(**self._count_attn_pages(host[1]))
         with tr.span("decode_launch"):
             logits, self.pool.state, tel = self._decode_fn(
                 self.params, self.pool.state, *args)

@@ -206,7 +206,11 @@ class SpeculativeEngine(Engine):
 
     # -- decode path -------------------------------------------------------
 
-    def _run_decode(self, decode: List[Request]) -> List[Tuple[int, int]]:
+    def _run_decode(self, decode: List[Request],
+                    span) -> List[Tuple[int, int]]:
+        # the cycle's kernel calls are spanned by spec_draft/spec_verify;
+        # ``decode_batch`` gets no attention page counts here
+        del span
         B, g = self._n_slots, self.spec.gamma
         token = np.zeros((B,), np.int32)
         pos = np.zeros((B,), np.int32)

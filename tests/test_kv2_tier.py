@@ -248,6 +248,49 @@ def test_tiered_kernel_demoted_page_equals_clamped_kv4():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
+
+def test_tiered_kernel_skips_pages_past_context():
+    """A demoted page inside the context, and table columns past each
+    sequence's last context page pointed at NaN-scaled pages of both
+    slabs (tier 0 and tier 1, in turn): the output equals that of the
+    clean table (tail at the null page) bit for bit, so neither slab is
+    read past the context."""
+    from repro.kernels.kv_attention import (context_pages,
+                                            kv_tiered_paged_decode_attention)
+    ps = 32
+    q, kp, ksp, vp, vsp, bt, _ = _paged_inputs(2, s=256, ps=ps)
+    pos = np.array([100, 37], np.int32)     # 4 and 2 context pages
+    n_ctx = context_pages(pos, ps)
+    bt = np.array(bt)
+    nan4 = int(bt[1, -1])                   # past row 1's context
+    ksp, vsp = ksp.at[nan4].set(jnp.nan), vsp.at[nan4].set(jnp.nan)
+    shape2 = (3,) + kp.shape[1:-1] + (kp.shape[-1] // 2,)
+    k2 = jax.random.randint(jax.random.PRNGKey(5), shape2, -128, 128,
+                            jnp.int8)
+    v2 = jax.random.randint(jax.random.PRNGKey(6), shape2, -128, 128,
+                            jnp.int8)
+    s2 = jnp.full(shape2[:3], 0.5, jnp.float32).at[2].set(jnp.nan)
+    tt = np.zeros_like(bt)
+    bt[0, 1], tt[0, 1] = 1, 1               # demoted: KV2 page 1
+    clean_bt, dirty_bt = bt.copy(), bt.copy()
+    dirty_tt = tt.copy()
+    for i in range(bt.shape[0]):
+        cols = np.arange(n_ctx[i], bt.shape[1])
+        clean_bt[i, cols] = 0
+        dirty_bt[i, cols] = nan4
+        dirty_bt[i, cols[::2]] = 2          # NaN-scaled KV2 page
+        dirty_tt[i, cols[::2]] = 1
+
+    def run(table, tiers):
+        return np.asarray(kv_tiered_paged_decode_attention(
+            q, kp, ksp, vp, vsp, k2, s2, v2, s2, jnp.asarray(table),
+            jnp.asarray(tiers), jnp.asarray(pos)))
+
+    clean = run(clean_bt, tt)
+    assert np.isfinite(clean).all()
+    np.testing.assert_array_equal(run(dirty_bt, dirty_tt), clean)
+
+
 # ---------------------------------------------------------------------------
 # engine end-to-end
 # ---------------------------------------------------------------------------

@@ -496,6 +496,49 @@ def test_engine_decode_and_schedule_spans_nest_in_order(qparams):
 
 
 @pytest.mark.slow
+def test_decode_batch_counts_the_attention_pages_it_reads(qparams):
+    """Each ``decode_batch`` span carries the paged kernel's page counts
+    for the step's positions (every decode slot, an idle one at pos 0),
+    and ``serving_paged_attn_pages_total{kind}`` adds up to slots x table
+    width x decode steps, its ``read`` part to the spans' pages."""
+    from repro.kernels.kv_attention import context_pages
+    clk = FakeClock(dt=0.001)
+    eng = Engine(CFG, qparams, clock=clk, obs=Observability(clock=clk))
+    rng = np.random.default_rng(2)
+    handles = [eng.submit(list(rng.integers(1, 127, size=n)),
+                          SamplingParams(max_new_tokens=m,
+                                         temperature=0.0))
+               for n, m in ((12, 30), (7, 6))]
+    ps, width, slots = (eng.pool.page_size, eng._n_page_steps,
+                        eng._n_slots)
+    expected = []
+    while eng.sched.has_work():
+        pos = [len(h.context) - 1 for h in handles
+               if h.out_tokens and not h.done]
+        n_batches = len(_spans(eng, "decode_batch"))
+        eng.step()
+        if len(_spans(eng, "decode_batch")) > n_batches:
+            expected.append(sum(int(context_pages(p, ps)) for p in pos)
+                            + slots - len(pos))
+    batches = _spans(eng, "decode_batch")
+    assert len(batches) == len(expected)
+    assert len(set(expected)) >= 3          # the contexts cross pages
+    assert [e["args"]["attn_pages"] for e in batches] == expected
+    assert {e["args"]["attn_page_steps"] for e in batches} == {
+        slots * width}
+    r = eng.obs.registry
+    read = r.value("serving_paged_attn_pages_total", kind="read")
+    skipped = r.value("serving_paged_attn_pages_total", kind="skipped")
+    assert read == sum(expected)
+    assert read + skipped == slots * width * len(batches)
+
+
+def _spans(eng, name):
+    return [e for e in eng.obs.tracer.export()["traceEvents"]
+            if e["ph"] == "X" and e["name"] == name]
+
+
+@pytest.mark.slow
 def test_engine_with_tracing_off_records_no_span_but_times_phases(qparams):
     eng = _run_tiny_engine(
         qparams, lambda clk: Observability(clock=clk, trace=False))

@@ -200,6 +200,97 @@ def test_paged_kernel_bitexact_vs_contiguous(b, s, kvh, g, hd, ps):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
+def test_context_pages_at_page_boundaries():
+    """Blocks holding positions 0..pos: a new one starts at each multiple
+    of the page size; the rule is the same on ints and arrays."""
+    from repro.kernels.kv_attention import context_pages
+    ps = 16
+    assert [context_pages(p, ps) for p in (0, 1, ps - 1, ps, ps + 1,
+                                           2 * ps - 1, 2 * ps)] == \
+        [1, 1, 1, 2, 2, 2, 3]
+    np.testing.assert_array_equal(
+        context_pages(np.array([0, ps - 1, ps, 191 * ps - 1], np.int32), ps),
+        [1, 1, 2, 191])
+
+
+# (b, g, kvh, hd, ps, n): an n-column table; the case names row 0's
+# position ("inactive": an all-null row at pos 0, as the engine pads)
+SKIP_SHAPE = (2, 2, 2, 32, 16, 8)
+SKIP_CASES = {"first": 0, "page_end": 15, "page_start": 16, "mid_page": 40,
+              "table_end": 127, "inactive": 0}
+
+
+def _kv_cache(b, s, kvh, hd, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    kq = jax.random.randint(keys[0], (b, s, kvh, hd // 2), -128, 128,
+                            jnp.int8)
+    vq = jax.random.randint(keys[1], (b, s, kvh, hd // 2), -128, 128,
+                            jnp.int8)
+    ks = jax.random.uniform(keys[2], (b, s, kvh), minval=0.1, maxval=1.0)
+    vs = jax.random.uniform(keys[3], (b, s, kvh), minval=0.1, maxval=1.0)
+    return [np.array(a) for a in (kq, ks, vq, vs)]
+
+
+def _paged(cache, ps, tables):
+    """Pool pages laid out from a contiguous cache: page ``1 + i*n + j``
+    holds row ``i``'s tokens [j*ps, (j+1)*ps); page 0 is null (zeros)
+    and the last page is all-NaN scales. Returns the pool and clean
+    tables (row i walks its own pages, or page 0 where ``tables[i]`` is
+    0)."""
+    b, s = cache[0].shape[:2]
+    n = s // ps
+    pool = [np.zeros((b * n + 2, ps) + a.shape[2:], a.dtype)
+            for a in cache]
+    for i in range(b):
+        for j in range(n):
+            for dst, src in zip(pool, cache):
+                dst[1 + i * n + j] = src[i, j * ps:(j + 1) * ps]
+    for k in (1, 3):                        # k and v scales of the NaN page
+        pool[k][-1] = np.nan
+    bt = np.array([(1 + i * n + np.arange(n)) * tables[i]
+                   for i in range(b)], np.int32)
+    return pool, bt
+
+
+@pytest.mark.parametrize("case", sorted(SKIP_CASES))
+def test_paged_kernel_skips_pages_past_context(case):
+    """Table columns past a sequence's last context page are never read:
+    pointed at a page whose scales are NaN (which would poison the
+    accumulator through p = 0 x NaN if the page were attended over),
+    the output equals the clean table's bit for bit. The contiguous
+    kernel, whose index map is not clamped, skips its NaN blocks by the
+    body's gate alone; and the paged kernel still equals it."""
+    from repro.kernels.kv_attention import (context_pages,
+                                            kv4_decode_attention,
+                                            kv4_paged_decode_attention)
+    b, g, kvh, hd, ps, n = SKIP_SHAPE
+    s = n * ps
+    q = jax.random.normal(jax.random.PRNGKey(9), (b, kvh, g, hd))
+    pos = np.array([SKIP_CASES[case], 77], np.int32)
+    cache = _kv_cache(b, s, kvh, hd)
+    pool, bt = _paged(cache, ps, [0 if case == "inactive" else 1, 1])
+    dirty = bt.copy()
+    for i in range(b):
+        dirty[i, context_pages(pos[i], ps):] = pool[0].shape[0] - 1
+    out = {name: np.asarray(kv4_paged_decode_attention(
+        q, *map(jnp.asarray, pool), jnp.asarray(table), jnp.asarray(pos)))
+        for name, table in (("clean", bt), ("dirty", dirty))}
+    assert np.isfinite(out["clean"]).all()
+    np.testing.assert_array_equal(out["dirty"], out["clean"])
+
+    if case == "inactive":
+        return
+    nan_tail = [a.copy() for a in cache]
+    for i in range(b):
+        for k in (1, 3):
+            nan_tail[k][i, context_pages(pos[i], ps) * ps:] = np.nan
+    contiguous = {name: np.asarray(kv4_decode_attention(
+        q, *map(jnp.asarray, c), jnp.asarray(pos), bs=ps))
+        for name, c in (("clean", cache), ("dirty", nan_tail))}
+    np.testing.assert_array_equal(contiguous["dirty"], contiguous["clean"])
+    np.testing.assert_array_equal(out["clean"], contiguous["clean"])
+
+
 # ---------------------------------------------------------------------------
 # engine vs legacy
 # ---------------------------------------------------------------------------

@@ -200,6 +200,38 @@ def test_verify_attention_bitexact_vs_single_token_loop():
                                       np.asarray(single))
 
 
+
+def test_verify_attention_skips_pages_past_each_window_token():
+    """A window that crosses a page boundary (positions 14, 15, 16 with
+    16-token pages): table columns past the window's last context page
+    point at a page whose scales are NaN, and the output equals the clean
+    table's bit for bit, so no window token reads them."""
+    from repro.kernels.kv_attention import (context_pages,
+                                            kv4_paged_verify_attention)
+    b, s, kvh, g, hd, ps, t = 2, 64, 2, 4, 32, 16, 3
+    n_per = s // ps
+    n_pages = b * n_per + 2                  # null page 0, NaN page last
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    kp = jax.random.randint(keys[0], (n_pages, ps, kvh, hd // 2), -128,
+                            128, jnp.int8)
+    vp = jax.random.randint(keys[1], (n_pages, ps, kvh, hd // 2), -128,
+                            128, jnp.int8)
+    ksp = jax.random.uniform(keys[2], (n_pages, ps, kvh), minval=0.1,
+                             maxval=1.0).at[-1].set(jnp.nan)
+    vsp = jax.random.uniform(keys[3], (n_pages, ps, kvh), minval=0.1,
+                             maxval=1.0).at[-1].set(jnp.nan)
+    bt = np.arange(1, b * n_per + 1, dtype=np.int32).reshape(b, n_per)
+    pos = np.array([14, 30], np.int32)
+    dirty = bt.copy()
+    for i in range(b):
+        dirty[i, context_pages(pos[i] + t - 1, ps):] = n_pages - 1
+    qT = jax.random.normal(keys[4], (b, t, kvh, g, hd))
+    clean, out = (np.asarray(kv4_paged_verify_attention(
+        qT, kp, ksp, vp, vsp, jnp.asarray(table), jnp.asarray(pos)))
+        for table in (bt, dirty))
+    assert np.isfinite(clean).all()
+    np.testing.assert_array_equal(out, clean)
+
 @pytest.mark.slow
 @pytest.mark.parametrize("cfg", [
     CFG,
