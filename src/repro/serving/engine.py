@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.kernels.kv_attention import context_pages
+from repro.kernels.kv_attention import block_pages, context_pages
 from repro.models.model import check_paged_support
 from repro.obs import Observability
 from repro.serving.kv_pool import PagedKVPool, PoolConfig
@@ -162,10 +162,14 @@ class Engine:
             "serving_tpot_seconds", "gap between consecutive emitted "
             "tokens of one request", unit="seconds")
         self._m_attn_pages = r.counter(
-            "serving_paged_attn_pages_total", "grid steps of one paged "
+            "serving_paged_attn_pages_total", "table columns of one paged "
             "decode-attention call (decode slots x block-table columns) "
-            "that read a context page or skip past the context",
+            "that hold a context page or lie past the context",
             unit="pages", labelnames=("kind",))
+        self._m_attn_block_pages = r.counter(
+            "serving_paged_attn_block_pages_total", "page slots of the "
+            "blocks one paged decode-attention call gathers (the blocks "
+            "holding context, whole)", unit="pages")
         self._m_wire = r.counter(
             "serving_wire_bytes_total", "measured packed-wire activation "
             "bytes (inter-layer hidden stream)", unit="bytes")
@@ -574,15 +578,23 @@ class Engine:
         return (token, pos, tables) + ((tiers,) if self._kv2 else ())
 
     def _count_attn_pages(self, pos: np.ndarray) -> Dict[str, int]:
-        """Grid steps of one paged decode-attention call at positions
-        ``pos`` (every decode slot, active or not) that read a context
-        page, and all of its grid steps; the kernel skips the rest.
-        Counted into ``serving_paged_attn_pages_total{kind}``."""
-        read = int(context_pages(pos, self.pool.page_size).sum())
+        """Table columns of one paged decode-attention call at positions
+        ``pos`` (every decode slot, active or not) that hold a context
+        page, all of its table columns (the kernel skips the rest), and
+        the page slots of the blocks that hold context, whole (the
+        kernel gathers :func:`block_pages` columns a block). Counted into
+        ``serving_paged_attn_pages_total{kind}`` and
+        ``serving_paged_attn_block_pages_total``."""
+        n = block_pages(self.pool.page_size, self._n_page_steps)
+        pages = context_pages(pos, self.pool.page_size)
+        read = int(pages.sum())
+        blocks = int((-(-pages // n) * n).sum())
         steps = pos.shape[0] * self._n_page_steps
         self._m_attn_pages.inc(read, kind="read")
         self._m_attn_pages.inc(steps - read, kind="skipped")
-        return {"attn_pages": read, "attn_page_steps": steps}
+        self._m_attn_block_pages.inc(blocks)
+        return {"attn_pages": read, "attn_page_steps": steps,
+                "attn_block_pages": blocks}
 
     def _run_decode(self, decode: List[Request],
                     span) -> List[Tuple[int, int]]:

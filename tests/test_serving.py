@@ -161,25 +161,34 @@ def test_pool_msb_sparsity_telemetry():
 @pytest.mark.slow
 @pytest.mark.parametrize("b,s,kvh,g,hd,ps", [
     (2, 256, 2, 4, 32, 64), (1, 256, 1, 2, 16, 128),
+    # 20 columns of 16 tokens: blocks of 8, 8 and a short 4
+    (2, 320, 2, 4, 32, 16),
 ])
 def test_paged_kernel_bitexact_vs_contiguous(b, s, kvh, g, hd, ps):
     """Walking a (shuffled) block table must reproduce the contiguous
-    kernel bit for bit — same body, same block shapes, same order."""
-    from repro.kernels.kv_attention import (kv4_decode_attention,
+    kernel at ``bs = block_pages * ps`` bit for bit — same body, same
+    block shapes, same order. Where the blocks overrun the table, the
+    contiguous cache runs on with NaN-scaled tokens, which the paged
+    kernel's short last block never holds: both mask them."""
+    from repro.kernels.kv_attention import (block_pages,
+                                            kv4_decode_attention,
                                             kv4_paged_decode_attention)
-    q = jax.random.normal(jax.random.PRNGKey(0), (b, kvh, g, hd))
-    kq = jax.random.randint(jax.random.PRNGKey(1), (b, s, kvh, hd // 2),
-                            -128, 128, jnp.int8)
-    vq = jax.random.randint(jax.random.PRNGKey(2), (b, s, kvh, hd // 2),
-                            -128, 128, jnp.int8)
-    ks = jax.random.uniform(jax.random.PRNGKey(3), (b, s, kvh),
-                            minval=0.1, maxval=1.0)
-    vs = jax.random.uniform(jax.random.PRNGKey(4), (b, s, kvh),
-                            minval=0.1, maxval=1.0)
-    pos = jax.random.randint(jax.random.PRNGKey(5), (b,), 1, s, jnp.int32)
-    ref = kv4_decode_attention(q, kq, ks, vq, vs, pos, bs=ps)
-
     n_per = s // ps
+    bs = block_pages(ps, n_per) * ps
+    s_pad = -(-s // bs) * bs
+    q = jax.random.normal(jax.random.PRNGKey(0), (b, kvh, g, hd))
+    kq = jax.random.randint(jax.random.PRNGKey(1), (b, s_pad, kvh, hd // 2),
+                            -128, 128, jnp.int8)
+    vq = jax.random.randint(jax.random.PRNGKey(2), (b, s_pad, kvh, hd // 2),
+                            -128, 128, jnp.int8)
+    ks = jax.random.uniform(jax.random.PRNGKey(3), (b, s_pad, kvh),
+                            minval=0.1, maxval=1.0).at[:, s:].set(jnp.nan)
+    vs = jax.random.uniform(jax.random.PRNGKey(4), (b, s_pad, kvh),
+                            minval=0.1, maxval=1.0).at[:, s:].set(jnp.nan)
+    pos = jax.random.randint(jax.random.PRNGKey(5), (b,), 1, s, jnp.int32)
+    ref = kv4_decode_attention(q, kq, ks, vq, vs, pos, bs=bs)
+    assert np.isfinite(np.asarray(ref)).all()
+
     n_pages = b * n_per + 1
     perm = np.random.RandomState(0).permutation(b * n_per) + 1
     kp = np.zeros((n_pages, ps, kvh, hd // 2), np.int8)
@@ -200,6 +209,14 @@ def test_paged_kernel_bitexact_vs_contiguous(b, s, kvh, g, hd, ps):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
+def test_block_pages_follows_page_size_and_table_width():
+    """About 128 tokens a block, at least one page, at most the table."""
+    from repro.kernels.kv_attention import block_pages
+    assert [block_pages(ps, 192) for ps in (8, 16, 32, 128, 256)] == \
+        [16, 8, 4, 1, 1]
+    assert [block_pages(16, n) for n in (1, 3, 8, 9)] == [1, 3, 8, 8]
+
+
 def test_context_pages_at_page_boundaries():
     """Blocks holding positions 0..pos: a new one starts at each multiple
     of the page size; the rule is the same on ints and arrays."""
@@ -213,11 +230,13 @@ def test_context_pages_at_page_boundaries():
         [1, 1, 2, 191])
 
 
-# (b, g, kvh, hd, ps, n): an n-column table; the case names row 0's
-# position ("inactive": an all-null row at pos 0, as the engine pads)
-SKIP_SHAPE = (2, 2, 2, 32, 16, 8)
+# (b, g, kvh, hd, ps, n): an n-column table, in blocks of 8, 8 and a
+# short 4 columns; the case names row 0's position ("inactive": an
+# all-null row at pos 0, as the engine pads)
+SKIP_SHAPE = (2, 2, 2, 32, 16, 20)
 SKIP_CASES = {"first": 0, "page_end": 15, "page_start": 16, "mid_page": 40,
-              "table_end": 127, "inactive": 0}
+              "block_last_page": 120, "block_first_page": 130,
+              "next_block": 256, "table_end": 319, "inactive": 0}
 
 
 def _kv_cache(b, s, kvh, hd, seed=0):
@@ -257,18 +276,22 @@ def test_paged_kernel_skips_pages_past_context(case):
     """Table columns past a sequence's last context page are never read:
     pointed at a page whose scales are NaN (which would poison the
     accumulator through p = 0 x NaN if the page were attended over),
-    the output equals the clean table's bit for bit. The contiguous
-    kernel, whose index map is not clamped, skips its NaN blocks by the
-    body's gate alone; and the paged kernel still equals it."""
-    from repro.kernels.kv_attention import (context_pages,
+    the output equals the clean table's bit for bit — whether the column
+    lies inside the last context block or past it. The contiguous
+    kernel, which copies whole blocks, masks the NaN rows of its last
+    context block and skips the blocks past it; and the paged kernel
+    still equals it at the same block size."""
+    from repro.kernels.kv_attention import (block_pages, context_pages,
                                             kv4_decode_attention,
                                             kv4_paged_decode_attention)
     b, g, kvh, hd, ps, n = SKIP_SHAPE
-    s = n * ps
+    bs = block_pages(ps, n) * ps
+    s = -(-n * ps // bs) * bs                 # the blocks overrun the table
     q = jax.random.normal(jax.random.PRNGKey(9), (b, kvh, g, hd))
     pos = np.array([SKIP_CASES[case], 77], np.int32)
     cache = _kv_cache(b, s, kvh, hd)
-    pool, bt = _paged(cache, ps, [0 if case == "inactive" else 1, 1])
+    pool, bt = _paged([a[:, :n * ps] for a in cache], ps,
+                      [0 if case == "inactive" else 1, 1])
     dirty = bt.copy()
     for i in range(b):
         dirty[i, context_pages(pos[i], ps):] = pool[0].shape[0] - 1
@@ -285,7 +308,7 @@ def test_paged_kernel_skips_pages_past_context(case):
         for k in (1, 3):
             nan_tail[k][i, context_pages(pos[i], ps) * ps:] = np.nan
     contiguous = {name: np.asarray(kv4_decode_attention(
-        q, *map(jnp.asarray, c), jnp.asarray(pos), bs=ps))
+        q, *map(jnp.asarray, c), jnp.asarray(pos), bs=bs))
         for name, c in (("clean", cache), ("dirty", nan_tail))}
     np.testing.assert_array_equal(contiguous["dirty"], contiguous["clean"])
     np.testing.assert_array_equal(out["clean"], contiguous["clean"])
