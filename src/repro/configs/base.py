@@ -69,6 +69,7 @@ class ModelConfig:
     norm_type: str = "rms"           # rms | layer (starcoder2, hubert)
     use_bias: bool = False           # linear biases (starcoder2, hubert)
     use_qk_norm: bool = False        # gemma3 per-head q/k RMSNorm
+    scale_embed: bool = False        # gemma: embeddings x sqrt(d_model)
 
     # SPARQLe quantized serving
     w_bits: int = 4

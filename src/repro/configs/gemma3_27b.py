@@ -20,6 +20,7 @@ CONFIG = ModelConfig(
     global_every=6,               # 5 local : 1 global
     mlp_type="geglu",
     use_qk_norm=True,
+    scale_embed=True,
     tie_embeddings=True,
 )
 

@@ -19,6 +19,7 @@ CONFIG = ModelConfig(
     rope_theta=10_000.0,
     mlp_type="geglu",
     tie_embeddings=True,
+    scale_embed=True,
     n_prefix=256,
 )
 

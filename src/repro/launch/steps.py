@@ -22,6 +22,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import spec_for
@@ -231,12 +232,53 @@ def make_serve_decode(cfg: ModelConfig):
 # continuous-batching engine steps (serving/engine.py) — paged KV pool
 # ---------------------------------------------------------------------------
 
-def _mesh_layout(cfg: ModelConfig, mesh: Mesh):
-    """(local cfg, model_ways, data axis name or None) for a step body."""
-    from repro.distributed.tp import mesh_axis_size, shard_model_config
+def _data_axis(mesh: Optional[Mesh]) -> Optional[str]:
+    """The mesh axis decode slots shard over: "data", or None without a
+    mesh or with a single data shard."""
+    from repro.distributed.tp import mesh_axis_size
+    if mesh is None or mesh_axis_size(mesh, "data") == 1:
+        return None
+    return "data"
+
+
+def _engine_step(step, cfg: ModelConfig, mesh: Optional[Mesh], param_specs,
+                 pool_specs, arg_specs, out_specs, batch_axis):
+    """``step(params, pool, *args, cfg=cfg)`` as an engine step.
+
+    ``step`` defaults its keyword ``cfg`` to the whole model's config.
+    Without a mesh it is returned as it is, so its name names the jitted
+    program (``jit_engine_decode``, which the benchmark's readers look
+    for). With a mesh it runs inside ``shard_map`` on the per-shard config
+    (weights model-partitioned, pool pages data-sharded; see
+    docs/sharding.md), with no ambient mesh and under a TP scope over
+    "model" whose batch axis is ``batch_axis``.
+    ``arg_specs`` are the partition specs of the arguments after params
+    and pool, ``out_specs`` those of the (logits, telemetry) results; the
+    pool keeps ``pool_specs``.
+    """
+    if mesh is None:
+        return step
+    from repro.distributed.sharding import no_mesh
+    from repro.distributed.tp import (mesh_axis_size, shard_model_config,
+                                      tp_scope)
     mways = mesh_axis_size(mesh, "model")
-    daxis = "data" if mesh_axis_size(mesh, "data") > 1 else None
-    return shard_model_config(cfg, mways), mways, daxis
+    lcfg = shard_model_config(cfg, mways)
+
+    def body(params, pool, *args):
+        with no_mesh(), tp_scope("model", mways, batch_axis=batch_axis):
+            return step(params, pool, *args, cfg=lcfg)
+
+    logits_spec, tel_specs = out_specs
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
+        in_specs=(param_specs, pool_specs, *arg_specs),
+        out_specs=(logits_spec, pool_specs, tel_specs))
+
+
+def _tel_specs(sparsity: P, per_layer: P) -> Dict[str, P]:
+    """A step's telemetry specs: its ``sparsity`` and its per-layer rows."""
+    return {"sparsity": sparsity, "layer_sparsity": per_layer,
+            "layer_wire_bytes": per_layer, "layer_dense_bytes": per_layer}
 
 
 def abstract_like(tree: Any) -> Any:
@@ -276,41 +318,30 @@ def make_engine_prefill_chunk(cfg: ModelConfig, *,
     compute into their null page; the owner's logits/telemetry are
     selected with an exact where-masked psum over the data axis.
     """
-    if mesh is None:
-        def prefill_chunk(params, pool, tokens, start, valid, block_table):
-            return M.prefill_chunk_paged(cfg, params, pool, tokens, start,
-                                         valid, block_table)
+    daxis = _data_axis(mesh)
 
-        return prefill_chunk
-
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import no_mesh
-    from repro.distributed.tp import tp_scope
-    lcfg, mways, daxis = _mesh_layout(cfg, mesh)
-
-    def body(params, pool, tokens, start, valid, table):
-        # prefill is replicated over data (the chunk's batch dim is 1):
-        # no batch_axis in the TP context, so MoE routes the local chunk
-        with no_mesh(), tp_scope("model", mways, batch_axis=None):
-            logits, pool, tel = M.prefill_chunk_paged(
-                lcfg, params, pool, tokens, start, valid, table)
+    def prefill_chunk(params, pool, tokens, start, valid, block_table, *,
+                      cfg=cfg):
+        logits, pool, tel = M.prefill_chunk_paged(
+            cfg, params, pool, tokens, start, valid, block_table)
         if daxis is not None:
             # exactly one data shard holds the sequence's pages (nonzero
             # block-table row); a where-masked psum selects its values
             # bit-exactly (a sum with a single nonzero term)
-            mine = jnp.any(table != 0)
+            mine = jnp.any(block_table != 0)
             sel = lambda t: jax.lax.psum(  # noqa: E731
                 jnp.where(mine, t, jnp.zeros_like(t)), daxis)
             logits = sel(logits)
             tel = {k: sel(v) for k, v in tel.items()}
         return logits, pool, tel
 
-    tel_specs = {"sparsity": P(), "layer_sparsity": P(None),
-                 "layer_wire_bytes": P(None), "layer_dense_bytes": P(None)}
-    return jax.shard_map(
-        body, mesh=mesh, check_vma=False,
-        in_specs=(param_specs, pool_specs, P(), P(), P(), P(daxis, None)),
-        out_specs=(P(), pool_specs, tel_specs))
+    # prefill is replicated over data (the chunk's batch dim is 1): no
+    # batch_axis in the TP context, so MoE routes the local chunk
+    return _engine_step(
+        prefill_chunk, cfg, mesh, param_specs, pool_specs,
+        arg_specs=(P(), P(), P(), P(daxis, None)),
+        out_specs=(P(), _tel_specs(P(), P(None))),
+        batch_axis=None)
 
 
 def make_engine_decode(cfg: ModelConfig, *, msb_skip: bool = False,
@@ -333,13 +364,13 @@ def make_engine_decode(cfg: ModelConfig, *, msb_skip: bool = False,
     the wire accounting from the traced program (telemetry comes back
     empty) — the draft runs γ times per emitted batch, so it stays lean.
 
-    ``kv2=True`` builds the precision-ladder decode step instead: the
-    returned function takes an extra ``tier_tables`` (B, Pmax) argument
-    after ``block_tables`` and reads each page from the slab its tier id
-    names (``models.model.decode_step_paged`` with ``tier_tables``; the
-    pool state must carry the KV2 slab, i.e. ``PoolConfig.kv2_pages >
-    0``). Unsharded engines only — the ladder's host bookkeeping is
-    single-pool.
+    The returned function takes an optional ``tier_tables`` (B, Pmax)
+    argument after ``block_tables``; given, the step reads each page from
+    the slab its tier id names (the precision ladder;
+    ``models.model.decode_step_paged`` with ``tier_tables``; the pool
+    state must carry the KV2 slab, i.e. ``PoolConfig.kv2_pages > 0``).
+    ``kv2=True`` declares that the engine passes it: unsharded engines
+    only — the ladder's host bookkeeping is single-pool.
 
     With a ``mesh``, the step runs inside shard_map: decode slots shard
     over the "data" axis (block tables carry the slot's data shard's
@@ -351,44 +382,22 @@ def make_engine_decode(cfg: ModelConfig, *, msb_skip: bool = False,
         raise NotImplementedError(
             "the KV2 precision ladder is unsharded-only (kv2=True with a "
             "mesh is not wired up; see docs/serving.md)")
-    if mesh is None:
-        if kv2:
-            def engine_decode_kv2(params, pool, token, pos, block_tables,
-                                  tier_tables):
-                return M.decode_step_paged(cfg, params, pool, token, pos,
-                                           block_tables,
-                                           tier_tables=tier_tables,
-                                           msb_skip=msb_skip,
-                                           with_telemetry=with_telemetry)
+    daxis = _data_axis(mesh)
 
-            return engine_decode_kv2
+    def engine_decode(params, pool, token, pos, block_tables,
+                      tier_tables=None, *, cfg=cfg):
+        return M.decode_step_paged(cfg, params, pool, token, pos,
+                                   block_tables, tier_tables=tier_tables,
+                                   msb_skip=msb_skip,
+                                   with_telemetry=with_telemetry)
 
-        def engine_decode(params, pool, token, pos, block_tables):
-            return M.decode_step_paged(cfg, params, pool, token, pos,
-                                       block_tables, msb_skip=msb_skip,
-                                       with_telemetry=with_telemetry)
-
-        return engine_decode
-
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import no_mesh
-    from repro.distributed.tp import tp_scope
-    lcfg, mways, daxis = _mesh_layout(cfg, mesh)
-
-    def body(params, pool, token, pos, tables):
-        with no_mesh(), tp_scope("model", mways, batch_axis=daxis):
-            return M.decode_step_paged(lcfg, params, pool, token, pos,
-                                       tables, msb_skip=msb_skip,
-                                       with_telemetry=with_telemetry)
-
-    B, LB = P(daxis), P(None, daxis)
-    tel_specs = ({"sparsity": B, "layer_sparsity": LB,
-                  "layer_wire_bytes": LB, "layer_dense_bytes": LB}
-                 if with_telemetry else {})
-    return jax.shard_map(
-        body, mesh=mesh, check_vma=False,
-        in_specs=(param_specs, pool_specs, B, B, P(daxis, None)),
-        out_specs=(P(daxis, None), pool_specs, tel_specs))
+    return _engine_step(
+        engine_decode, cfg, mesh, param_specs, pool_specs,
+        arg_specs=(P(daxis), P(daxis), P(daxis, None)),
+        out_specs=(P(daxis, None),
+                   _tel_specs(P(daxis), P(None, daxis))
+                   if with_telemetry else {}),
+        batch_axis=daxis)
 
 
 def make_engine_verify_window(cfg: ModelConfig, *,
@@ -406,31 +415,18 @@ def make_engine_verify_window(cfg: ModelConfig, *,
     With a ``mesh``, sharded exactly like :func:`make_engine_decode`
     (the window axis T stays per-shard-complete).
     """
-    if mesh is None:
-        def engine_verify(params, pool, tokens, pos, block_tables):
-            return M.verify_window_paged(cfg, params, pool, tokens, pos,
-                                         block_tables)
+    daxis = _data_axis(mesh)
 
-        return engine_verify
+    def engine_verify(params, pool, tokens, pos, block_tables, *, cfg=cfg):
+        return M.verify_window_paged(cfg, params, pool, tokens, pos,
+                                     block_tables)
 
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import no_mesh
-    from repro.distributed.tp import tp_scope
-    lcfg, mways, daxis = _mesh_layout(cfg, mesh)
-
-    def body(params, pool, tokens, pos, tables):
-        with no_mesh(), tp_scope("model", mways, batch_axis=daxis):
-            return M.verify_window_paged(lcfg, params, pool, tokens, pos,
-                                         tables)
-
-    B, LB = P(daxis), P(None, daxis)
-    tel_specs = {"sparsity": B, "layer_sparsity": LB,
-                 "layer_wire_bytes": LB, "layer_dense_bytes": LB}
-    return jax.shard_map(
-        body, mesh=mesh, check_vma=False,
-        in_specs=(param_specs, pool_specs, P(daxis, None), B,
-                  P(daxis, None)),
-        out_specs=(P(daxis, None, None), pool_specs, tel_specs))
+    return _engine_step(
+        engine_verify, cfg, mesh, param_specs, pool_specs,
+        arg_specs=(P(daxis, None), P(daxis), P(daxis, None)),
+        out_specs=(P(daxis, None, None),
+                   _tel_specs(P(daxis), P(None, daxis))),
+        batch_axis=daxis)
 
 
 def pool_abstract_and_shardings(cfg: ModelConfig, n_pages: int,

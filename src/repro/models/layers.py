@@ -237,18 +237,6 @@ def act_wire_telemetry(x: jax.Array) -> dict:
         }
 
 
-def stack_sublayer_telemetry(tels: list) -> dict:
-    """Stack per-sub-layer telemetry dicts into per-key (period, ...) arrays.
-
-    Shared by every paged step (decode / prefill-chunk / verify-window):
-    inside the stage scan each sub-layer contributes one
-    :func:`act_wire_telemetry` dict; the scan then stacks the period axis
-    under the repeat axis and the caller flattens (repeat, period, ...)
-    to per-layer rows.
-    """
-    return {k: jnp.stack([t[k] for t in tels], 0) for k in tels[0]}
-
-
 # ---------------------------------------------------------------------------
 # embedding / head
 # ---------------------------------------------------------------------------

@@ -37,8 +37,7 @@ from repro.models import moe as moe_lib
 from repro.models import ssd as ssd_lib
 from repro.models.layers import (AttnSpec, NEG_INF, act_wire_telemetry,
                                  decode_attention, embed, flash_attention,
-                                 layer_norm, rms_norm, rope,
-                                 stack_sublayer_telemetry)
+                                 layer_norm, rms_norm, rope)
 from repro.models.stages import LayerDef, Stage, build_stages
 
 Params = Dict[str, Any]
@@ -513,41 +512,78 @@ def _stage_scan_full(cfg, stage: Stage, sparams, x, positions, prefix_len,
     return x, caches, aux
 
 
-def _stage_scan_decode(cfg, stage: Stage, sparams, scache, x, pos):
-    def body(carry, inp):
-        h = carry
-        pslice, cslice = inp
-        new_c = {}
-        for pi, ld in enumerate(stage.period):
-            h, c = _apply_layer_decode(cfg, ld, pslice[f"p{pi}"], h,
-                                       cslice[f"p{pi}"], pos)
-            new_c[f"p{pi}"] = c
-        return h, new_c
+def _scan_layers_cached(cfg, params: Params, caches: Cache, x: jax.Array,
+                        layer, telemetry: bool = False):
+    """Every stage's layer scan over its params and cache (or pool) slices.
 
-    x, new_cache = jax.lax.scan(body, x, (sparams, scache))
-    return x, new_cache
+    The decode-like forwards (legacy and paged decode, verify, chunked
+    prefill) differ only in ``layer(ld, p, h, c) -> (h, c)``: one
+    sub-layer over its cache slice. With ``telemetry`` the hidden stream
+    entering each sub-layer is measured (``act_wire_telemetry``).
+    Returns (x, new caches, per-layer telemetry (L, ...) or {}).
+    """
+    new_caches: Cache = {"stages": {}}
+    layer_tels = []
+    for si, stage in enumerate(build_stages(cfg)):
+        def body(h, inp, stage=stage):
+            pslice, cslice = inp
+            tels = []
+            new_c = {}
+            for pi, ld in enumerate(stage.period):
+                if telemetry:
+                    tels.append(act_wire_telemetry(h))  # one per SUB-layer
+                h, c = layer(ld, pslice[f"p{pi}"], h, cslice[f"p{pi}"])
+                new_c[f"p{pi}"] = c
+            tel = ({k: jnp.stack([t[k] for t in tels], 0) for k in tels[0]}
+                   if telemetry else {})
+            return h, (new_c, tel)
+
+        x, (nc, tel) = jax.lax.scan(body, x, (params["stages"][f"s{si}"],
+                                              caches["stages"][f"s{si}"]))
+        new_caches["stages"][f"s{si}"] = nc
+        # scan stacks to (repeat, period, ...): flatten to (L_s, ...)
+        layer_tels.append({k: v.reshape(-1, *v.shape[2:])
+                           for k, v in tel.items()})
+    tel = {k: jnp.concatenate([t[k] for t in layer_tels], axis=0)
+           for k in layer_tels[0]}
+    return x, new_caches, tel
 
 
 # ---------------------------------------------------------------------------
 # embedding / head
 # ---------------------------------------------------------------------------
 
+def _embed(cfg: ModelConfig, params: Params, tokens: Optional[jax.Array],
+           prefix: Optional[jax.Array] = None) -> jax.Array:
+    """Input embeddings in the compute dtype.
+
+    The precomputed ``prefix`` embeddings (if any) followed along axis 1 by
+    the table rows of ``tokens`` (if any), times sqrt(d_model) where the
+    config sets ``scale_embed`` (gemma).
+    """
+    dt = cfg.cdtype
+    x = None
+    if tokens is not None:
+        x = embed(tokens, params["embed"]["table"]).astype(dt)
+    if prefix is not None:
+        prefix = prefix.astype(dt)
+        x = prefix if x is None else jnp.concatenate([prefix, x], axis=1)
+    if cfg.scale_embed:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
+    return x
+
+
 def embed_inputs(cfg: ModelConfig, params: Params,
                  batch: Dict[str, jax.Array]):
     """Returns (x (B,S,D), positions (S,), prefix_len)."""
-    dt = cfg.cdtype
     prefix_len = 0
-    if cfg.family == "encoder":
-        x = batch["frames"].astype(dt)        # stub frontend: precomputed
-    elif cfg.family == "vlm":
-        patches = batch["patches"].astype(dt)  # stub SigLIP: precomputed
-        tok = embed(batch["tokens"], params["embed"]["table"]).astype(dt)
-        x = jnp.concatenate([patches, tok], axis=1)
-        prefix_len = patches.shape[1]
+    if cfg.family == "encoder":               # stub frontend: precomputed
+        x = _embed(cfg, params, None, batch["frames"])
+    elif cfg.family == "vlm":                 # stub SigLIP: precomputed
+        x = _embed(cfg, params, batch["tokens"], batch["patches"])
+        prefix_len = batch["patches"].shape[1]
     else:
-        x = embed(batch["tokens"], params["embed"]["table"]).astype(dt)
-    if cfg.family == "vlm" or cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dt)   # gemma embed scaling
+        x = _embed(cfg, params, batch["tokens"])
     positions = jnp.arange(x.shape[1])
     return x, positions, prefix_len
 
@@ -615,22 +651,13 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Cache,
                 embeds: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, Cache]:
     """One decode step. token (B,) int32, pos (B,) int32 -> logits (B, V)."""
-    dt = cfg.cdtype
     if cfg.family == "encoder":
         raise ValueError("encoder-only model has no decode step")
-    if embeds is not None:
-        x = embeds.astype(dt)
-    else:
-        x = embed(token, params["embed"]["table"]).astype(dt)
-    if cfg.family == "vlm" or cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
+    x = _embed(cfg, params, token if embeds is None else None, embeds)
     x = constrain(x, ("batch", "embed"))
-    new_cache: Cache = {"stages": {}}
-    for si, stage in enumerate(build_stages(cfg)):
-        x, nc = _stage_scan_decode(
-            cfg, stage, params["stages"][f"s{si}"],
-            cache["stages"][f"s{si}"], x, pos)
-        new_cache["stages"][f"s{si}"] = nc
+    x, new_cache, _ = _scan_layers_cached(
+        cfg, params, cache, x,
+        lambda ld, p, h, c: _apply_layer_decode(cfg, ld, p, h, c, pos))
     logits = head_logits(cfg, params, x[:, None, :])[:, 0]
     return logits, new_cache
 
@@ -672,6 +699,39 @@ def _act_subprecision_sparsity(x: jax.Array) -> jax.Array:
     return subprecision_sparsity(q, axis=-1)
 
 
+def _write_kv_pages(pool: Cache, block_tables: jax.Array,
+                    positions: jax.Array, kq: jax.Array, ks: jax.Array,
+                    vq: jax.Array, vs: jax.Array,
+                    keep: Optional[jax.Array] = None) -> Cache:
+    """Scatter quantized K/V into the pool pages of their positions.
+
+    ``positions`` (B,) or (B, T) are absolute token positions, row b's
+    read through ``block_tables[b]``; K/V and their scales carry the same
+    leading dims. A token whose ``keep`` is False writes into the null
+    page 0 instead: a prefill chunk's padded tail, or a decode token whose
+    page sits in the KV2 slab. A KV2 slab in the pool passes through.
+    """
+    ps = pool["k_q"].shape[1]
+    n_steps = block_tables.shape[1]
+    if positions.ndim == 1:
+        rows = jnp.arange(block_tables.shape[0])
+        page = block_tables[rows, jnp.clip(positions // ps, 0, n_steps - 1)]
+    else:
+        page = jnp.take_along_axis(
+            block_tables, jnp.clip(positions // ps, 0, n_steps - 1), axis=1)
+    if keep is not None:
+        page = jnp.where(keep, page, 0)
+    off = positions % ps
+    with jax.named_scope("kv_write"):
+        return {
+            **pool,
+            "k_q": pool["k_q"].at[page, off].set(kq),
+            "k_s": pool["k_s"].at[page, off].set(ks),
+            "v_q": pool["v_q"].at[page, off].set(vq),
+            "v_s": pool["v_s"].at[page, off].set(vs),
+        }
+
+
 def attn_decode_paged(cfg: ModelConfig, ld: LayerDef, p: Params,
                       x: jax.Array, pool: Cache, block_tables: jax.Array,
                       pos: jax.Array,
@@ -696,24 +756,13 @@ def attn_decode_paged(cfg: ModelConfig, ld: LayerDef, p: Params,
     q, k_new, v_new = _attn_qkv(cfg, p, h, pos, theta)
     kq, ks = _kv_quant(cfg, k_new)
     vq, vs = _kv_quant(cfg, v_new)
-    ps = pool["k_q"].shape[1]
-    n_steps = block_tables.shape[1]
-    bidx = jnp.arange(b)
-    page = block_tables[bidx, jnp.clip(pos // ps, 0, n_steps - 1)]
+    keep = None
     if tier_tables is not None:
         # a demoted page id indexes the KV2 slab — never scatter there
-        page = jnp.where(
-            tier_tables[bidx, jnp.clip(pos // ps, 0, n_steps - 1)] == 0,
-            page, 0)
-    off = pos % ps
-    with jax.named_scope("kv_write"):
-        pool = {
-            **pool,                   # KV2 slab (if any) passes through
-            "k_q": pool["k_q"].at[page, off].set(kq),
-            "k_s": pool["k_s"].at[page, off].set(ks),
-            "v_q": pool["v_q"].at[page, off].set(vq),
-            "v_s": pool["v_s"].at[page, off].set(vs),
-        }
+        ps, n_steps = pool["k_q"].shape[1], tier_tables.shape[1]
+        keep = tier_tables[jnp.arange(b),
+                           jnp.clip(pos // ps, 0, n_steps - 1)] == 0
+    pool = _write_kv_pages(pool, block_tables, pos, kq, ks, vq, vs, keep)
     with jax.named_scope("paged_attention"):
         if tier_tables is None:
             o = kv4_paged_decode_attention(
@@ -726,13 +775,6 @@ def attn_decode_paged(cfg: ModelConfig, ld: LayerDef, p: Params,
                 pool["v2_q"], pool["v2_s"], block_tables, tier_tables, pos)
     o = o.reshape(b, cfg.n_heads * cfg.hd)
     return linear(o, p["wo"], p.get("bo"), tp="row"), pool
-
-
-def _apply_layer_decode_paged(cfg, ld: LayerDef, p: Params, x, pool,
-                              block_tables, pos, tier_tables=None):
-    y, pool = attn_decode_paged(cfg, ld, p, x, pool, block_tables, pos,
-                                tier_tables)
-    return _apply_ffn_decode(cfg, ld, p, x + y), pool
 
 
 def decode_step_paged(cfg: ModelConfig, params: Params, pool: Cache,
@@ -767,49 +809,20 @@ def decode_step_paged(cfg: ModelConfig, params: Params, pool: Cache,
     ``tier_tables`` (B, Pmax) arms the KV2 precision-ladder read path
     (see :func:`attn_decode_paged`); None keeps the KV4-only program.
     """
+    def layer(ld, p, h, c):
+        y, c = attn_decode_paged(cfg, ld, p, h, c, block_tables, pos,
+                                 tier_tables)
+        return _apply_ffn_decode(cfg, ld, p, h + y), c
+
     with msb_skip_scope(msb_skip):
-        return _decode_step_paged_body(cfg, params, pool, token, pos,
-                                       block_tables, with_telemetry,
-                                       tier_tables)
-
-
-def _decode_step_paged_body(cfg, params, pool, token, pos, block_tables,
-                            with_telemetry, tier_tables=None):
-    dt = cfg.cdtype
-    x = embed(token, params["embed"]["table"]).astype(dt)
-    if cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
-    x = constrain(x, ("batch", "embed"))
-    new_pool: Cache = {"stages": {}}
-    layer_tels = []
-    for si, stage in enumerate(build_stages(cfg)):
-        def body(h, inp, stage=stage):
-            pslice, cslice = inp
-            tels = []
-            new_c = {}
-            for pi, ld in enumerate(stage.period):
-                if with_telemetry:
-                    tels.append(act_wire_telemetry(h))  # one per SUB-layer
-                h, c = _apply_layer_decode_paged(
-                    cfg, ld, pslice[f"p{pi}"], h, cslice[f"p{pi}"],
-                    block_tables, pos, tier_tables)
-                new_c[f"p{pi}"] = c
-            tel = stack_sublayer_telemetry(tels) if with_telemetry else {}
-            return h, (new_c, tel)
-
-        x, (nc, tel) = jax.lax.scan(body, x, (params["stages"][f"s{si}"],
-                                              pool["stages"][f"s{si}"]))
-        new_pool["stages"][f"s{si}"] = nc
-        # scan stacks to (repeat, period, B): flatten to per-layer (L_s, B)
-        layer_tels.append({k: v.reshape(-1, *v.shape[2:])
-                           for k, v in tel.items()})
-    telemetry: Dict[str, jax.Array] = {}
-    if with_telemetry:
-        telemetry["sparsity"] = _act_subprecision_sparsity(x)
-        for key in ("sparsity", "wire_bytes", "dense_bytes"):
-            telemetry[f"layer_{key}"] = jnp.concatenate(
-                [t[key] for t in layer_tels], axis=0)
-    logits = head_logits(cfg, params, x[:, None, :])[:, 0]
+        x = constrain(_embed(cfg, params, token), ("batch", "embed"))
+        x, new_pool, tel = _scan_layers_cached(cfg, params, pool, x, layer,
+                                               with_telemetry)
+        telemetry: Dict[str, jax.Array] = {}
+        if with_telemetry:
+            telemetry["sparsity"] = _act_subprecision_sparsity(x)
+            telemetry.update({f"layer_{k}": v for k, v in tel.items()})
+        logits = head_logits(cfg, params, x[:, None, :])[:, 0]
     return logits, new_pool, telemetry
 
 
@@ -835,18 +848,7 @@ def attn_verify_paged(cfg: ModelConfig, ld: LayerDef, p: Params,
     q, k_new, v_new = _attn_qkv(cfg, p, h, positions, theta)
     kq, ks = _kv_quant(cfg, k_new)
     vq, vs = _kv_quant(cfg, v_new)
-    ps = pool["k_q"].shape[1]
-    n_steps = block_tables.shape[1]
-    step = jnp.clip(positions // ps, 0, n_steps - 1)
-    page = jnp.take_along_axis(block_tables, step, axis=1)  # (B, T)
-    off = positions % ps
-    pool = {
-        **pool,                       # KV2 slab (if any) passes through
-        "k_q": pool["k_q"].at[page, off].set(kq),
-        "k_s": pool["k_s"].at[page, off].set(ks),
-        "v_q": pool["v_q"].at[page, off].set(vq),
-        "v_s": pool["v_s"].at[page, off].set(vs),
-    }
+    pool = _write_kv_pages(pool, block_tables, positions, kq, ks, vq, vs)
     o = kv4_paged_verify_attention(
         q.reshape(b, t, kvh, g, cfg.hd), pool["k_q"], pool["k_s"],
         pool["v_q"], pool["v_s"], block_tables, pos)
@@ -880,53 +882,29 @@ def verify_window_paged(cfg: ModelConfig, params: Params, pool: Cache,
         packed-wire vs dense int8 bytes summed over the window's
         inter-layer hidden stream.
     """
-    dt = cfg.cdtype
-    x = embed(tokens, params["embed"]["table"]).astype(dt)   # (B, T, D)
-    if cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
-    x = constrain(x, ("batch", "seq", "embed"))
-    new_pool: Cache = {"stages": {}}
-    layer_tels = []
-    for si, stage in enumerate(build_stages(cfg)):
-        def body(h, inp, stage=stage):
-            pslice, cslice = inp
-            tels = []
-            new_c = {}
-            for pi, ld in enumerate(stage.period):
-                tels.append(act_wire_telemetry(h))   # one per SUB-layer
-                y, c = attn_verify_paged(
-                    cfg, ld, pslice[f"p{pi}"], h, cslice[f"p{pi}"],
-                    block_tables, pos)
-                h = h + y
-                if ld.ffn == "dense":
-                    h = h + dense_ffn(cfg, pslice[f"p{pi}"], h)
-                elif ld.ffn == "moe":
-                    # one routed-MoE call PER WINDOW POSITION: expert
-                    # capacity is a function of the flat token count
-                    # (t * top_k * cf // E), so batching all B*T window
-                    # tokens into one dispatch would drop different
-                    # assignments than the B-token sequential decode
-                    # steps this function must be bit-exact against
-                    h = h + jnp.concatenate(
-                        [moe_ffn(cfg, pslice[f"p{pi}"],
-                                 h[:, t:t + 1])[0]
-                         for t in range(h.shape[1])], axis=1)
-                new_c[f"p{pi}"] = c
-            return h, (new_c, stack_sublayer_telemetry(tels))
+    def layer(ld, p, h, c):
+        y, c = attn_verify_paged(cfg, ld, p, h, c, block_tables, pos)
+        h = h + y
+        if ld.ffn == "dense":
+            h = h + dense_ffn(cfg, p, h)
+        elif ld.ffn == "moe":
+            # one routed-MoE call PER WINDOW POSITION: expert capacity is
+            # a function of the flat token count (t * top_k * cf // E), so
+            # batching all B*T window tokens into one dispatch would drop
+            # different assignments than the B-token sequential decode
+            # steps this function must be bit-exact against
+            h = h + jnp.concatenate(
+                [moe_ffn(cfg, p, h[:, t:t + 1])[0]
+                 for t in range(h.shape[1])], axis=1)
+        return h, c
 
-        x, (nc, tel) = jax.lax.scan(body, x, (params["stages"][f"s{si}"],
-                                              pool["stages"][f"s{si}"]))
-        new_pool["stages"][f"s{si}"] = nc
-        # scan stacks to (repeat, period, B, T): flatten to (L_s, B, T)
-        layer_tels.append({k: v.reshape(-1, *v.shape[2:])
-                           for k, v in tel.items()})
-    cat = lambda key: jnp.concatenate(  # noqa: E731
-        [t[key] for t in layer_tels], axis=0)
-    telemetry = {
+    x = constrain(_embed(cfg, params, tokens), ("batch", "seq", "embed"))
+    x, new_pool, tel = _scan_layers_cached(cfg, params, pool, x, layer, True)
+    telemetry = {                      # per-layer (L, B, T) over the window
         "sparsity": _act_subprecision_sparsity(x).mean(axis=-1),
-        "layer_sparsity": cat("sparsity").mean(axis=-1),
-        "layer_wire_bytes": cat("wire_bytes").sum(axis=-1),
-        "layer_dense_bytes": cat("dense_bytes").sum(axis=-1),
+        "layer_sparsity": tel["sparsity"].mean(axis=-1),
+        "layer_wire_bytes": tel["wire_bytes"].sum(axis=-1),
+        "layer_dense_bytes": tel["dense_bytes"].sum(axis=-1),
     }
     logits = head_logits(cfg, params, x)                     # (B, T, V)
     return logits, new_pool, telemetry
@@ -955,18 +933,8 @@ def _attn_prefill_chunk_paged(cfg: ModelConfig, ld: LayerDef, p: Params,
     n_steps = block_table.shape[1]
     kq, ks = _kv_quant(cfg, k)
     vq, vs = _kv_quant(cfg, v)
-    valid_tok = jnp.arange(c) < valid
-    page = jnp.where(valid_tok,
-                     block_table[0, jnp.clip(positions // ps, 0,
-                                             n_steps - 1)], 0)
-    off = positions % ps
-    pool = {
-        **pool,                       # KV2 slab (if any) passes through
-        "k_q": pool["k_q"].at[page, off].set(kq[0]),
-        "k_s": pool["k_s"].at[page, off].set(ks[0]),
-        "v_q": pool["v_q"].at[page, off].set(vq[0]),
-        "v_s": pool["v_s"].at[page, off].set(vs[0]),
-    }
+    pool = _write_kv_pages(pool, block_table, positions[None], kq, ks, vq,
+                           vs, keep=(jnp.arange(c) < valid)[None])
 
     # context = dequantized pool pages [0, start) ++ float chunk K/V
     kp = pool["k_q"][block_table[0]].reshape(n_steps * ps, kvh, hd // 2)
@@ -1007,49 +975,29 @@ def prefill_chunk_paged(cfg: ModelConfig, params: Params, pool: Cache,
     of the chunk's valid tokens on that inter-layer stream
     (``layers.act_wire_telemetry``).
     """
-    dt = cfg.cdtype
-    x = embed(tokens, params["embed"]["table"]).astype(dt)
-    if cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
-    x = constrain(x, ("batch", "seq", "embed"))
-    new_pool: Cache = {"stages": {}}
-    layer_tels = []
-    for si, stage in enumerate(build_stages(cfg)):
-        def body(h, inp, stage=stage):
-            pslice, cslice = inp
-            tels = []
-            new_c = {}
-            for pi, ld in enumerate(stage.period):
-                tels.append(act_wire_telemetry(h))   # one per SUB-layer
-                y, c = _attn_prefill_chunk_paged(
-                    cfg, ld, pslice[f"p{pi}"], h, cslice[f"p{pi}"],
-                    block_table, start, valid)
-                h = h + y
-                if ld.ffn == "dense":
-                    h = h + dense_ffn(cfg, pslice[f"p{pi}"], h)
-                elif ld.ffn == "moe":
-                    h = h + moe_ffn(cfg, pslice[f"p{pi}"], h)[0]
-                new_c[f"p{pi}"] = c
-            return h, (new_c, stack_sublayer_telemetry(tels))
+    def layer(ld, p, h, c):
+        y, c = _attn_prefill_chunk_paged(cfg, ld, p, h, c, block_table,
+                                         start, valid)
+        h = h + y
+        if ld.ffn == "dense":
+            h = h + dense_ffn(cfg, p, h)
+        elif ld.ffn == "moe":
+            h = h + moe_ffn(cfg, p, h)[0]
+        return h, c
 
-        x, (nc, tel) = jax.lax.scan(body, x, (params["stages"][f"s{si}"],
-                                              pool["stages"][f"s{si}"]))
-        new_pool["stages"][f"s{si}"] = nc
-        # scan stacks to (repeat, period, 1, C): flatten to (L_s, 1, C)
-        layer_tels.append({k: v.reshape(-1, *v.shape[2:])
-                           for k, v in tel.items()})
+    x = constrain(_embed(cfg, params, tokens), ("batch", "seq", "embed"))
+    x, new_pool, tel = _scan_layers_cached(cfg, params, pool, x, layer, True)
     last = jnp.maximum(valid - 1, 0)
     valid_tok = (jnp.arange(tokens.shape[1]) < valid).astype(jnp.float32)
     n_valid = jnp.maximum(jnp.sum(valid_tok), 1.0)
     sp_tok = _act_subprecision_sparsity(x[0])
-    # per-layer stats over the chunk's VALID tokens only
-    cat = lambda key: jnp.concatenate(  # noqa: E731
-        [t[key][:, 0, :] for t in layer_tels], axis=0)
+    # per-layer (L, 1, C) stats over the chunk's VALID tokens only
+    lay = {k: v[:, 0, :] for k, v in tel.items()}
     telemetry = {
         "sparsity": jnp.sum(sp_tok * valid_tok) / n_valid,
-        "layer_sparsity": jnp.sum(cat("sparsity") * valid_tok, -1) / n_valid,
-        "layer_wire_bytes": jnp.sum(cat("wire_bytes") * valid_tok, -1),
-        "layer_dense_bytes": jnp.sum(cat("dense_bytes") * valid_tok, -1),
+        "layer_sparsity": jnp.sum(lay["sparsity"] * valid_tok, -1) / n_valid,
+        "layer_wire_bytes": jnp.sum(lay["wire_bytes"] * valid_tok, -1),
+        "layer_dense_bytes": jnp.sum(lay["dense_bytes"] * valid_tok, -1),
     }
     x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
     logits = head_logits(cfg, params, x_last)[:, 0]

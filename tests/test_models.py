@@ -107,6 +107,32 @@ def test_smoke_decode_consistency(name):
     assert rel < 0.12, rel
 
 
+def test_scale_embed_set_exactly_for_gemma_configs():
+    """The sqrt(d_model) embedding scale is a config field, not a test of
+    the model's name: the registry sets it for gemma3-27b and paligemma-3b
+    only, and a smoke decode with it set differs from one with it
+    cleared."""
+    assert sorted(n for n, c in ARCHS.items() if c.scale_embed) == [
+        "gemma3-27b", "paligemma-3b"]
+    assert all(SMOKES[n].scale_embed == ARCHS[n].scale_embed for n in ARCHS)
+    cfg = SMOKES["paligemma-3b"].replace(kv_bits=8)
+    params = init_params(build_schema(cfg), jax.random.PRNGKey(0))
+    B, S = 2, 12
+    batch = _batch(cfg, b=B, s=S, train=False)
+    token = jnp.full((B,), 7, jnp.int32)
+
+    def smoke_decode(c):
+        _, cache = M.prefill(c, params, batch, max_len=S + 1)
+        lg, _ = M.decode_step(c, params, cache, token,
+                              jnp.full((B,), S, jnp.int32))
+        return np.asarray(lg, np.float32)
+
+    scaled = smoke_decode(cfg)
+    plain = smoke_decode(cfg.replace(scale_embed=False))
+    assert np.isfinite(scaled).all() and np.isfinite(plain).all()
+    assert np.abs(scaled - plain).max() > 1e-2
+
+
 @pytest.mark.parametrize("name", _mark_slow(
     ["granite-8b", "deepseek-moe-16b", "jamba-v0.1-52b", "mamba2-2.7b"]))
 def test_smoke_sparqle_serving(name):

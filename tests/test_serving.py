@@ -524,10 +524,13 @@ def test_engine_stream_and_temperature(qparams):
     assert all(0 <= t < CFG.vocab for t in got)
 
 
-def test_decode_paged_telemetry_covers_every_sublayer():
+@pytest.mark.parametrize("entry", ["decode", "verify", "prefill"])
+def test_decode_paged_telemetry_covers_every_sublayer(entry):
     """Per-layer telemetry must have one entry per LAYER, not per scanned
     period: a GQA MoE config with moe_every=2 (period length 2) passes
-    check_paged_support and must still report n_layers wire-byte rows."""
+    check_paged_support and must still report n_layers wire-byte rows —
+    from each paged entry point (decode; verify of a 3-token window; one
+    4-token prefill chunk), since all three share the layer scan."""
     cfg = ModelConfig(name="tiny-moe-serve", family="moe", n_layers=4,
                       d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
                       d_ff=64, vocab=64, dtype="float32", n_experts=4,
@@ -537,15 +540,28 @@ def test_decode_paged_telemetry_covers_every_sublayer():
     from repro.serving.kv_pool import PagedKVPool, PoolConfig
     params = init_params(build_schema(cfg), jax.random.PRNGKey(1))
     pool = PagedKVPool(cfg, PoolConfig(n_pages=4, page_size=4))
-    token = jnp.zeros((2,), jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
     tables = jnp.zeros((2, 2), jnp.int32)
-    _, _, tel = M.decode_step_paged(cfg, params, pool.state, token, pos,
-                                    tables)
-    assert tel["layer_wire_bytes"].shape == (cfg.n_layers, 2)
-    assert tel["layer_sparsity"].shape == (cfg.n_layers, 2)
+    if entry == "decode":
+        _, _, tel = M.decode_step_paged(cfg, params, pool.state,
+                                        jnp.zeros((2,), jnp.int32), pos,
+                                        tables)
+        rows, n_tok = (cfg.n_layers, 2), 1
+    elif entry == "verify":            # bytes summed over the window
+        _, _, tel = M.verify_window_paged(cfg, params, pool.state,
+                                          jnp.zeros((2, 3), jnp.int32),
+                                          pos, tables)
+        rows, n_tok = (cfg.n_layers, 2), 3
+    else:                              # bytes summed over valid tokens
+        _, _, tel = M.prefill_chunk_paged(
+            cfg, params, pool.state, jnp.zeros((1, 4), jnp.int32),
+            jnp.asarray(0, jnp.int32), jnp.asarray(4, jnp.int32),
+            tables[:1])
+        rows, n_tok = (cfg.n_layers,), 4
+    assert tel["layer_wire_bytes"].shape == rows
+    assert tel["layer_sparsity"].shape == rows
     np.testing.assert_allclose(np.asarray(tel["layer_dense_bytes"]),
-                               np.full((cfg.n_layers, 2), cfg.d_model))
+                               np.full(rows, cfg.d_model * n_tok))
 
 
 def test_scheduler_token_budget_and_fcfs():
