@@ -18,13 +18,16 @@ pallas_call) and asserts the representation contracts:
   ``dot_general`` the dataflow stays integer-typed until the single
   ``convert_element_type`` rescale; no float op touches the accumulator.
 * **JXP004** — msb_skip elision: the draft jaxpr holds exactly half the
-  int32 matmuls of the full step, and none of its matmul operands are
-  produced by the MSB-plane extraction (arithmetic right shift).
+  int32 matmuls of the full step, and none of its matmul activation
+  operands are produced by the MSB-plane extraction (arithmetic right
+  shift). The weight operand is not looked at: packed int4 weights
+  reach the dots as nibble planes sign-extended by the same shift.
 * **JXP005** — no ``pure_callback`` / ``io_callback`` /
   ``debug_callback`` (jax.debug.print) inside any serving step.
 
 Empirical anchors (tiny 2-layer configs): the full decode
-carries 16 int8-plane dots (8 of them shift-fed MSB dots), the draft 8
+carries 32 int8-plane dots (16 of them shift-fed MSB dots; each pass of
+a packed linear is two dots, one per weight nibble plane), the draft 16
 (0 shift-fed); a 2x2 mesh decode carries exactly 2 int32 ``psum`` and
 2 f32 ``pmax`` eqns over the model axis.
 """
@@ -339,7 +342,9 @@ def _unique_jaxprs(jaxpr: Jaxpr):
 
 
 def count_int_plane_dots(jaxpr: Jaxpr) -> Tuple[int, int]:
-    """(total int8-plane dots, dots fed by an MSB-plane right shift)."""
+    """(total int8-plane dots, dots whose activation operand (the lhs)
+    is fed by an MSB-plane right shift, through layout ops and the
+    strided column slices of a packed weight's parity split)."""
     total = shift_fed = 0
     for sub, _, _ in _unique_jaxprs(jaxpr):
         producer = {}
@@ -354,7 +359,7 @@ def count_int_plane_dots(jaxpr: Jaxpr) -> Tuple[int, int]:
             e = producer[var]
             if e.primitive.name == "shift_right_arithmetic":
                 return True
-            if e.primitive.name in _LAYOUT_PRIMS:
+            if e.primitive.name in _LAYOUT_PRIMS | {"slice"}:
                 return any(from_shift(iv, depth + 1) for iv in e.invars
                            if not isinstance(iv, Literal))
             return False
@@ -362,7 +367,7 @@ def count_int_plane_dots(jaxpr: Jaxpr) -> Tuple[int, int]:
         for eqn in sub.eqns:
             if _is_int_plane_dot(eqn):
                 total += 1
-                if any(from_shift(iv) for iv in eqn.invars):
+                if from_shift(eqn.invars[0]):
                     shift_fed += 1
     return total, shift_fed
 

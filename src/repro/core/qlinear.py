@@ -19,7 +19,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import re
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -73,10 +73,18 @@ def pack_int4(q: jax.Array, axis: int = -2) -> jax.Array:
         jnp.left_shift(jnp.bitwise_and(hi, 0xF), 4)).astype(jnp.int8)
 
 
+def int4_planes(q: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The two sign-extended nibble planes of :func:`pack_int4` bytes.
+
+    ``lo`` holds the even rows along the packed axis and ``hi`` the odd
+    ones, each the packed array's shape: elementwise shifts, no relayout.
+    """
+    return jnp.right_shift(jnp.left_shift(q, 4), 4), jnp.right_shift(q, 4)
+
+
 def unpack_int4(q: jax.Array, axis: int = -2) -> jax.Array:
     """Inverse of :func:`pack_int4` (sign-extending)."""
-    lo = jnp.right_shift(jnp.left_shift(q, 4), 4)
-    hi = jnp.right_shift(q, 4)
+    lo, hi = int4_planes(q)
     stacked = jnp.stack([lo, hi], axis=axis + 1 if axis >= 0
                         else q.ndim + axis + 1)
     shape = list(q.shape)
@@ -135,7 +143,38 @@ class SparqleLinear:
         return tuple(s)
 
 
-def _dual_pass_matmul(q: jax.Array, wq: jax.Array, batched: bool,
+# The weight operand of the integer dots: an int8 (…, K, N) array, or the
+# sign-extended (lo, hi) nibble planes of a packed (…, K/2, N) one.
+WeightOperand = Union[jax.Array, Tuple[jax.Array, jax.Array]]
+
+
+def _int_dot(a: jax.Array, w: WeightOperand, batched: bool) -> jax.Array:
+    """int8 (M, K) x (K, N), or batched (E, C, K) x (E, K, N), -> int32.
+
+    For packed planes, row 2i of the unpacked weight is ``lo[i]`` and row
+    2i+1 is ``hi[i]``, so ``a @ unpack(W) == a[..., 0::2] @ lo +
+    a[..., 1::2] @ hi`` exactly in int32: the K-interleaved int8 weight
+    is never built. A row-parallel shard holding packed rows [r, s) holds
+    unpacked rows [2r, 2s) and the matching activation columns, so the
+    parity split is local to each shard.
+    """
+    dims = ((((2,), (1,)), ((0,), (0,))) if batched
+            else (((1,), (0,)), ((), ())))
+
+    def dot(x, y):
+        return jax.lax.dot_general(x, y, dims,
+                                   preferred_element_type=jnp.int32)
+
+    if isinstance(w, tuple):
+        lo, hi = w
+        k = a.shape[-1]
+        even, odd = (jax.lax.slice_in_dim(a, p, k, stride=2, axis=a.ndim - 1)
+                     for p in (0, 1))
+        return dot(even, lo) + dot(odd, hi)
+    return dot(a, w)
+
+
+def _dual_pass_matmul(q: jax.Array, w: WeightOperand, batched: bool,
                       wire_format: str = "unpacked",
                       msb_skip: bool = False) -> jax.Array:
     """int8 SPARQLe activations x int-weights -> int32, dual nibble passes.
@@ -162,26 +201,19 @@ def _dual_pass_matmul(q: jax.Array, wq: jax.Array, batched: bool,
         else:
             act = encode(q)
             lsb, msb = act.lsb4, act.msb4
-    if batched:   # (E, C, K) x (E, K, N)
-        dims = (((2,), (1,)), ((0,), (0,)))
-    else:         # (M, K) x (K, N)
-        dims = (((1,), (0,)), ((), ()))
     with jax.named_scope("lsb_dot"):
-        dense = jax.lax.dot_general(lsb, wq, dims,
-                                    preferred_element_type=jnp.int32)
+        dense = _int_dot(lsb, w, batched)
     if msb_skip:
         return dense
     with jax.named_scope("msb_dot"):
-        sparse = jax.lax.dot_general(msb, wq, dims,
-                                     preferred_element_type=jnp.int32)
+        sparse = _int_dot(msb, w, batched)
     return dense + sparse * 16
 
 
-def _single_pass_matmul(q: jax.Array, wq: jax.Array, batched: bool) -> jax.Array:
-    dims = (((2,), (1,)), ((0,), (0,))) if batched else (((1,), (0,)), ((), ()))
+def _single_pass_matmul(q: jax.Array, w: WeightOperand,
+                        batched: bool) -> jax.Array:
     with jax.named_scope("int8_dot"):
-        return jax.lax.dot_general(q, wq, dims,
-                                   preferred_element_type=jnp.int32)
+        return _int_dot(q, w, batched)
 
 
 def linear(x: jax.Array, w, b: Optional[jax.Array] = None, *,
@@ -252,13 +284,16 @@ def _quantized_apply(x: jax.Array, sl: SparqleLinear,
     if sl.col_mask is not None and sl.l is not None:
         mask = sl.col_mask[:, None, :] if batched else sl.col_mask
         q = apply_clipping(q, mask, sl.l, sl.h)
+    # a packed weight reaches the dots as its two nibble planes (see
+    # _int_dot), so no int8 copy of it is built on the call
     with jax.named_scope("weight_unpack"):
-        wq = sl.unpacked_q()
+        w = (int4_planes(sl.w.q.astype(jnp.int8)) if sl.packed
+             else sl.unpacked_q())
     if sl.mode == "sparqle":
-        acc = _dual_pass_matmul(q, wq, batched, sl.wire_format,
+        acc = _dual_pass_matmul(q, w, batched, sl.wire_format,
                                 msb_skip=_MSB_SKIP)
     else:
-        acc = _single_pass_matmul(q, wq, batched)
+        acc = _single_pass_matmul(q, w, batched)
     if row:
         # ONE reduction per linear: the dual-pass accumulator already
         # merged LSB and shifted-MSB partials, and int32 addition is
@@ -267,10 +302,10 @@ def _quantized_apply(x: jax.Array, sl: SparqleLinear,
         acc = jax.lax.psum(acc, ctx.axis)
     w_scale = sl.w.scale  # (1, N) or (E, 1, N) per-output-channel
     out = acc.astype(jnp.float32) * qa.scale.astype(jnp.float32) \
-        * w_scale.reshape((wq.shape[0], 1, -1) if batched else (1, -1))
+        * w_scale.reshape((sl.w.q.shape[0], 1, -1) if batched else (1, -1))
     if batched:
         return out.astype(x.dtype)
-    return out.reshape(*orig[:-1], wq.shape[-1]).astype(x.dtype)
+    return out.reshape(*orig[:-1], sl.w.q.shape[-1]).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -421,3 +456,27 @@ def quantize_model_params(
         return out
 
     return walk(params)
+
+
+def packed_projection_counts(params: Dict[str, Any]) -> Dict[str, int]:
+    """Served projection slices by the weight path their dots take.
+
+    ``nibble_planes``: packed leaves, whose dots read the two int4 planes
+    (:func:`_int_dot`); ``unpacked``: int8 weights (odd K, w_bits > 4).
+    A layer-stacked leaf counts once per layer slice, an expert leaf
+    once (its experts are one batched dot).
+    """
+    counts = {"nibble_planes": 0, "unpacked": 0}
+
+    def walk(tree, prefix=""):
+        for k, v in tree.items():
+            path = f"{prefix}/{k}" if prefix else k
+            if isinstance(v, dict):
+                walk(v, path)
+            elif isinstance(v, SparqleLinear):
+                _, stacked = leaf_plan(path, v.w.q.ndim)
+                counts["nibble_planes" if v.packed else "unpacked"] += (
+                    v.w.q.shape[0] if stacked else 1)
+
+    walk(params)
+    return counts

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.qlinear import packed_projection_counts
 from repro.kernels.kv_attention import block_pages, context_pages
 from repro.models.model import check_paged_support
 from repro.obs import Observability
@@ -97,6 +98,8 @@ class Engine:
             self._data_ways = 1
             self._param_specs = self._pool_specs = None
         self.params = params
+        for path, n in packed_projection_counts(params).items():
+            self._g_packed.set(n, path=path)
         self.pool = PagedKVPool(cfg, pool_config,
                                 n_shards=self._data_ways, obs=self.obs)
         if self.mesh is not None:
@@ -176,6 +179,10 @@ class Engine:
         self._m_dense = r.counter(
             "serving_dense_bytes_total", "dense int8 baseline bytes for "
             "the same activations", unit="bytes")
+        self._g_packed = r.gauge(
+            "serving_packed_projections", "served projection slices (one "
+            "per layer of a stacked leaf) by the weight path of their "
+            "dots", unit="projections", labelnames=("path",))
         self._g_pool_free = r.gauge(
             "serving_pool_pages_free", "free pages across all shards",
             unit="pages")

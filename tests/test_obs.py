@@ -17,6 +17,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.core.qlinear import quantize_model_params
+from repro.models.registry import get_config
 from repro.models.schema import init_params
 from repro.models.schema_builder import build_schema
 from repro.obs import (DEFAULT_LATENCY_BUCKETS, MetricsRegistry,
@@ -531,6 +532,32 @@ def test_decode_batch_counts_the_attention_pages_it_reads(qparams):
     skipped = r.value("serving_paged_attn_pages_total", kind="skipped")
     assert read == sum(expected)
     assert read + skipped == slots * width * len(batches)
+
+
+@pytest.mark.parametrize("w_bits,path,other", [
+    (4, "nibble_planes", "unpacked"), (8, "unpacked", "nibble_planes")])
+def test_engine_gauges_served_projections_by_weight_path(w_bits, path,
+                                                         other):
+    """``serving_packed_projections{path}`` counts every projection slice
+    of the served tree once, on the path its dots take: nibble planes
+    for packed W4 leaves, the int8 weight for W8 ones."""
+    fparams = init_params(build_schema(CFG), jax.random.PRNGKey(0))
+    eng = Engine(CFG, quantize_model_params(fparams, w_bits=w_bits,
+                                            tile_k=16))
+    r = eng.obs.registry
+    # q, k, v, o, gate, up and down in each layer, and the lm_head
+    assert r.value("serving_packed_projections",
+                   path=path) == CFG.n_layers * 7 + 1
+    assert r.value("serving_packed_projections", path=other) == 0
+
+
+def test_granite_serves_every_projection_on_nibble_planes():
+    from repro.core.qlinear import packed_projection_counts
+    from repro.models.qschema import build_quantized_schema, tree_abstract
+    cfg = get_config("granite-8b")
+    served = tree_abstract(build_quantized_schema(build_schema(cfg)))
+    assert packed_projection_counts(served) == {
+        "nibble_planes": cfg.n_layers * 7, "unpacked": 0}
 
 
 def _spans(eng, name):

@@ -1,4 +1,6 @@
 """Quantization substrate: W4/W2/A8/KV4 + the qlinear dispatch layer."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +11,8 @@ except ModuleNotFoundError:      # degrade to seeded fixed examples
     from _hypothesis_fallback import given, settings, st
 
 from repro.core.qlinear import (SparqleLinear, expert_linear, linear,
-                                quantize_leaf, quantize_model_params)
+                                msb_skip_scope, quantize_leaf,
+                                quantize_model_params)
 from repro.core.quantize import (fake_quantize, quantize_activations,
                                  quantize_kv, quantize_weights)
 from repro.core.sparqle import subprecision_sparsity
@@ -106,6 +109,55 @@ def test_expert_linear_quantized():
     cos = float((yf * yq).sum() /
                 (jnp.linalg.norm(yf) * jnp.linalg.norm(yq)))
     assert cos > 0.98
+
+
+@pytest.mark.parametrize(
+    "mode,wire_format,msb_skip,batched,k", [
+        ("sparqle", "unpacked", False, False, 256),
+        ("sparqle", "unpacked", False, False, 200),
+        ("sparqle", "packed", False, False, 200),
+        ("sparqle", "unpacked", True, False, 200),
+        ("sparqle", "packed", True, False, 96),
+        ("dense", "unpacked", False, False, 200),
+        ("sparqle", "unpacked", False, True, 200),
+        ("sparqle", "packed", True, True, 96),
+        ("dense", "unpacked", False, True, 96),
+    ])
+def test_packed_weight_dots_equal_unpacked_weight_bit_for_bit(
+        mode, wire_format, msb_skip, batched, k):
+    """A packed leaf's dots over the two nibble planes give exactly the
+    output of the same leaf served with its unpacked int8 weight (K not a
+    multiple of 128 included)."""
+    rng = np.random.RandomState(k + 7 * batched)
+    lead = (3, 5) if batched else (5,)
+    x = rng.randn(*lead, k).astype(np.float32)
+    x[..., ::9] *= 6.0                  # outliers: a populated MSB4 plane
+    w = rng.randn(*lead[:-1], k, 24).astype(np.float32)
+    sl = quantize_leaf(jnp.asarray(w), mode=mode, wire_format=wire_format,
+                       tile_k=8)
+    assert sl.packed
+    ref_sl = dataclasses.replace(
+        sl, w=dataclasses.replace(sl.w, q=sl.unpacked_q()), packed=False)
+    apply = expert_linear if batched else linear
+    with msb_skip_scope(msb_skip):
+        got = apply(jnp.asarray(x), sl)
+        ref = apply(jnp.asarray(x), ref_sl)
+    assert got.shape == ref.shape == (*lead, 24)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("w_bits,path", [(4, "nibble_planes"),
+                                         (8, "unpacked")])
+def test_packed_projection_counts_one_per_layer_slice(w_bits, path):
+    from repro.core.qlinear import packed_projection_counts
+    params = {"stages": {"s0": {"p0": {
+        "wq": jnp.ones((3, 16, 32)),                 # stacked (L,K,N)
+        "moe": {"w_gate": jnp.ones((4, 16, 8))},     # experts (E,K,N)
+        "ln": {"gamma": jnp.zeros((3, 16))},
+    }}}, "lm_head": jnp.ones((16, 64))}
+    counts = packed_projection_counts(
+        quantize_model_params(params, w_bits=w_bits, tile_k=8))
+    assert counts == {"nibble_planes": 0, "unpacked": 0, path: 5}
 
 
 def test_quantize_model_params_structure():

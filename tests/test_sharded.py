@@ -11,6 +11,8 @@ All tests take the `mesh` fixture and skip when the host exposes too few
 devices; the CI `test-multidevice` lane runs them on 8 forced CPU
 devices.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,6 +51,40 @@ def test_sparqle_linear_sharded_bit_exact(mesh, ways, wire_format,
     got = sparqle_linear_sharded(x, w, mesh=m, axis="model",
                                  partition=partition, **kw)
     assert got.shape == ref.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("ways", [2, 4])
+@pytest.mark.parametrize("mode,wire_format", [
+    ("sparqle", "unpacked"), ("sparqle", "packed"), ("dense", "unpacked")])
+def test_packed_row_parallel_linear_bit_exact(mesh, ways, mode,
+                                              wire_format):
+    """``linear(..., tp="row")`` on a packed leaf, K sharded in packed
+    rows: each shard's nibble-plane dots pair its own even/odd activation
+    columns, and the psum'd output equals the single-device output of
+    the same leaf with its unpacked int8 weight."""
+    from repro.core.qlinear import linear, quantize_leaf
+    from repro.distributed import tp
+    m = mesh(model=ways)
+    rng = np.random.RandomState(5)
+    x = rng.randn(6, 128).astype(np.float32)
+    x[:, ::9] *= 6.0
+    sl = quantize_leaf(jnp.asarray(rng.randn(128, 24).astype(np.float32)),
+                       mode=mode, wire_format=wire_format, tile_k=16)
+    assert sl.packed
+    unpacked = dataclasses.replace(
+        sl, w=dataclasses.replace(sl.w, q=sl.unpacked_q()), packed=False)
+    ref = linear(jnp.asarray(x), unpacked)
+
+    def body(xs, w):
+        with tp.tp_scope("model", ways):
+            return linear(xs, w, tp="row")
+
+    fn = jax.shard_map(
+        body, mesh=m, check_vma=False,
+        in_specs=(P(None, "model"), tp.param_pspecs({"wo": sl})["wo"]),
+        out_specs=P(None, None))
+    got = fn(jnp.asarray(x), sl)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 

@@ -17,6 +17,7 @@ and under several test workers only the one given this file loads it.
 Keep these tests in this one file for the same reason.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -130,3 +131,11 @@ def test_engine_decode_step_compiles_at_granite_width(one_chip):
     _assert_kernel(compiled, "kv4_paged_decode_attention")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    # packed W4 weights reach the dots as their two nibble planes: no
+    # int8 weight of an unpacked (K, N) shape, nor the (K/2, 2, N) stack
+    # that interleaves the planes back into one, is built
+    text = compiled.as_text()
+    d, kv, ff = cfg.d_model, cfg.n_kv_heads * cfg.hd, cfg.d_ff
+    for k, n in ((d, ff), (ff, d), (d, d), (d, kv)):
+        for dims in (f"{k},{n}", f"{k // 2},2,{n}"):
+            assert not re.search(rf"s8\[(\d+,)*{dims}\]", text), dims
